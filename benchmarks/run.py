@@ -29,6 +29,8 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     failures = []
     for mod_name in MODULES:

@@ -51,6 +51,7 @@ from benchmarks.common import emit, get_trained_essr, timed
 from repro.api import ExecutionPlan, SREngine
 from repro.core.adaptive import SwitchingConfig
 from repro.core.pipeline import edge_selective_sr
+from repro.launch.cache import REPO_ROOT
 from repro.launch.mesh import make_patch_mesh
 from repro.models.essr import ESSRConfig, init_essr
 from repro.runtime.guard import FaultPlan
@@ -657,7 +658,7 @@ def main():
                  f"mpixels_per_s={hr_pix / us:.2f}{note}")
 
     # TPU projection from the dry-run artifact
-    f = "/root/repo/results/dryrun/single/essr-x4__serve_8k.json"
+    f = str(REPO_ROOT / "results/dryrun/single/essr-x4__serve_8k.json")
     if os.path.exists(f):
         d = json.load(open(f))
         r = d["roofline"]

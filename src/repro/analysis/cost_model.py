@@ -33,7 +33,7 @@ from typing import Any, Dict, Tuple
 
 import numpy as np
 import jax
-from jax.core import ClosedJaxpr, Jaxpr
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 from repro.analysis.jaxpr_audit import _sub_jaxprs, entry_point_specs
 

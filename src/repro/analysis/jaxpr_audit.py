@@ -4,7 +4,7 @@ Traces the engine's compiled surfaces (`core.pipeline.fused_frame_fn`, the
 multi-stream admission tick `fused_stream_frame_fn`, the sharded shard_map
 forward, the integer qconv kernel chain, `edge_score`)
 with `jax.make_jaxpr` on a small-but-representative configuration and walks
-every equation — including nested pjit / shard_map / pallas_call / control-
+every equation — including nested jit / shard_map / pallas_call / control-
 flow sub-jaxprs — for the graph hazards the 8K@30FPS budget cannot absorb:
 
   ESSR101  host callbacks / infeed-outfeed transfers inside the graph: a
@@ -39,7 +39,7 @@ from typing import Callable, Dict, Iterator, List, Tuple
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax.core import ClosedJaxpr, Jaxpr, Literal
+from jax.extend.core import ClosedJaxpr, Jaxpr, Literal
 
 from repro.analysis.report import Violation
 
@@ -71,7 +71,7 @@ def _sub_jaxprs(params: Dict) -> Iterator[ClosedJaxpr | Jaxpr]:
 
 
 def iter_eqns(jaxpr: Jaxpr) -> Iterator:
-    """Every equation of ``jaxpr``, recursing into sub-jaxprs (pjit bodies,
+    """Every equation of ``jaxpr``, recursing into sub-jaxprs (jit bodies,
     shard_map bodies, pallas kernels, scan/cond/while branches, custom-vjp
     call jaxprs)."""
     for eqn in jaxpr.eqns:

@@ -3,7 +3,7 @@
 Where the jaxpr audit (pass 1) pattern-matches *hazards*, this pass computes
 *guarantees*: a forward abstract interpretation that propagates value
 intervals through every equation of an entry point's jaxpr — including
-nested pjit / custom_jvp / shard_map bodies and the Pallas kernel jaxprs
+nested jit / custom_jvp / shard_map bodies and the Pallas kernel jaxprs
 themselves (refs modeled as cells, ``get``/``swap`` as reads/unions) — and
 certifies the integer datapath the PAMS serving path runs on:
 
@@ -52,7 +52,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax.core import ClosedJaxpr, Jaxpr, Literal
+from jax.extend.core import ClosedJaxpr, Jaxpr, Literal
 
 from repro.analysis.jaxpr_audit import entry_point_specs
 from repro.analysis.report import Violation
@@ -428,7 +428,7 @@ def _eval_addupdate(interp, eqn, invals):
 
 
 _STRUCTURED: Dict[str, Callable] = {
-    "pjit": lambda i, e, v: _call_sub(i, e, v, "jaxpr"),
+    "jit": lambda i, e, v: _call_sub(i, e, v, "jaxpr"),
     "closed_call": lambda i, e, v: _call_sub(i, e, v, "call_jaxpr"),
     "core_call": lambda i, e, v: _call_sub(i, e, v, "call_jaxpr"),
     "remat2": lambda i, e, v: _call_sub(i, e, v, "jaxpr"),

@@ -61,14 +61,17 @@ from repro.core.pipeline import (compiled_cache_occupancy,
                                  sanitize_frame, snap_capacity,
                                  sr_all_patches_result, sr_whole)
 from repro.kernels.dispatch import resolve_interpret
+from repro.launch.cache import REPO_ROOT
 from repro.launch.mesh import make_patch_mesh
 from repro.models.essr import ESSRConfig, init_essr
 from repro.runtime.guard import (FaultInjector, PoisonFrameError,
                                  ResilienceGuard)
 
 #: Default location of the cached briefly-trained benchmark supernets
-#: (written by benchmarks/common.get_trained_essr).
-DEFAULT_BENCH_CACHE = os.environ.get("BENCH_CACHE", "/root/repo/results/bench_models")
+#: (written by benchmarks/common.get_trained_essr), under the checkout's
+#: root; ``BENCH_CACHE`` overrides it.
+DEFAULT_BENCH_CACHE = os.environ.get(
+    "BENCH_CACHE", str(REPO_ROOT / "results" / "bench_models"))
 
 MODES = ("edge_select", "all_patches", "whole")
 
@@ -112,14 +115,9 @@ class SREngine:
         # serving resilience (repro.runtime.guard): the sticky degradation
         # ladder from this engine's configured serving point, plus the
         # optional seeded fault harness (plan.faults). Engine state like the
-        # mesh — the ladder level survives across frames by design. The
-        # ladder is built from the RESOLVED interpret policy so the
-        # pallas->interpret rung only exists where compiled kernels actually
-        # run (on CPU the interpreter is already the resolved mode).
+        # mesh — the ladder level survives across frames by design.
         self.guard = ResilienceGuard(
-            backend=backend,
-            interpret=(resolve_interpret(self.plan.interpret)
-                       if backend == "pallas" else self.plan.interpret),
+            backend=backend, interpret=self.plan.interpret,
             quant_on=self.plan.quant is not None, fusion=self.plan.fusion,
             max_retries=self.plan.max_retries)
         self.injector = (FaultInjector(self.plan.faults)
@@ -444,7 +442,7 @@ class SREngine:
             return fn(self.params, frame, t1, t2)
 
         # the degradation ladder owns retries: a failed launch (injected or
-        # genuine) steps down fusion -> interpret -> ref -> fp32 and re-runs
+        # genuine) steps down fusion -> ref -> fp32 and re-runs
         outs, steps = self.guard.run(attempt, index)
         v = self.guard.variant
         compiled = self._mark_warm(("fused", geom.cache_key, caps,

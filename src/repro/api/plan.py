@@ -245,7 +245,7 @@ class ExecutionPlan:
     faults: Optional[FaultPlan] = None
     #: Extra launch attempts the degradation ladder may spend per frame/tick
     #: (`runtime.guard.ResilienceGuard`): on a failed launch the engine steps
-    #: down (fusion group->layer, backend pallas->interpret->ref, quant
+    #: down (fusion group->layer, backend pallas->ref, quant
     #: ->fp32; sticky) or retries at the ref/fp32/layer floor, at most this
     #: many times, then re-raises.
     max_retries: int = 2

@@ -21,11 +21,16 @@ LAPLACIAN = jnp.array([[0.0, 1.0, 0.0],
 
 
 def laplacian_response(luma: jax.Array) -> jax.Array:
-    """(N,H,W) luma in [0,255] -> (N,H-2,W-2) |Laplacian| clamped to [0,255]."""
+    """(N,H,W) luma in [0,255] -> (N,H-2,W-2) |Laplacian| clamped to [0,255].
+
+    The conv runs at full fp32 precision on every backend: the TPU's default
+    rounds conv inputs to bf16 (luma off by up to 0.5), which would move
+    patches across the routing thresholds."""
     k = LAPLACIAN.reshape(3, 3, 1, 1)
     y = lax.conv_general_dilated(
         luma[..., None], k, window_strides=(1, 1), padding="VALID",
-        dimension_numbers=("NHWC", "HWIO", "NHWC"))[..., 0]
+        dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        precision=lax.Precision.HIGHEST)[..., 0]
     return jnp.clip(jnp.abs(y), 0.0, 255.0)
 
 

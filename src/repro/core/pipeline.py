@@ -199,10 +199,9 @@ def _sharded_forward_fn(backend: str, mesh, cfg: ESSRConfig, width: int,
     axis, params replicated. Cached per (backend, mesh, cfg, width, interpret,
     quant, fusion) so the shard_map callable (and its compiled executable) is
     built once per routing regime (`QuantPack` is frozen/hashable for exactly
-    this). ``check_rep=False``: pallas_call has no replication rule, and the
+    this). ``check_vma=False``: pallas_call has no replication rule, and the
     batch axis carries no collectives anyway."""
     from repro.distributed.sharding import patch_batch_spec
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     forward = resolve_forward(backend, quant, fusion)
@@ -211,8 +210,8 @@ def _sharded_forward_fn(backend: str, mesh, cfg: ESSRConfig, width: int,
     def local(params, patches):
         return forward(params, patches, cfg, width, interpret=interpret)
 
-    return jax.jit(shard_map(local, mesh=mesh, in_specs=(P(), spec),
-                             out_specs=spec, check_rep=False))
+    return jax.jit(jax.shard_map(local, mesh=mesh, in_specs=(P(), spec),
+                                 out_specs=spec, check_vma=False))
 
 
 def sharded_forward(params, patches: jax.Array, cfg: ESSRConfig, width: int,

@@ -13,7 +13,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 NEG_INF = -1e30
@@ -50,11 +49,11 @@ def flash_decode_attention(mesh: Mesh, axis: str, q, k_cache, v_cache, length):
         out = o_sum / jnp.maximum(l_sum[..., None], 1e-30)
         return out.reshape(b, 1, h, d).astype(q.dtype)
 
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(P(None, None, None, None),
-                             P(None, axis, None, None), P(None, axis, None, None),
-                             P()),
-                   out_specs=P(None, None, None, None))
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(P(None, None, None, None),
+                                 P(None, axis, None, None),
+                                 P(None, axis, None, None), P()),
+                       out_specs=P(None, None, None, None))
     return fn(q, k_cache, v_cache, length)
 
 
@@ -81,8 +80,8 @@ def compressed_psum(mesh: Mesh, axis: str, grads, error_state):
             n = lax.psum(jnp.ones((), jnp.float32), axis)
             return total / n, new_err
 
-        return shard_map(local, mesh=mesh, in_specs=(P(), P()),
-                         out_specs=(P(), P()))(g, err)
+        return jax.shard_map(local, mesh=mesh, in_specs=(P(), P()),
+                             out_specs=(P(), P()))(g, err)
 
     flat_g, tdef = jax.tree_util.tree_flatten(grads)
     flat_e = tdef.flatten_up_to(error_state)

@@ -23,7 +23,6 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.configs.base import LMConfig
@@ -122,11 +121,11 @@ def moe_forward_shardmap(p: Dict[str, Any], x: jax.Array, cfg: LMConfig,
     # un-SP'd (P(dp, None, None)); the surrounding constraints re-shard.
     # ep_alltoall keeps tokens mp-sharded (each shard dispatches its own).
     x_spec = P(dp, mp, None) if ep else P(dp, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(x_spec, P(None, None), w_spec, w_spec, wo_spec),
         out_specs=(x_spec, P(None)),
-        check_rep=False)
+        check_vma=False)
 
     # pad B/S to mesh multiples (e.g. deepseek's MTP shifts S to 4095); the
     # pad tokens route like real ones but their outputs are sliced off.

@@ -18,12 +18,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-# jax < 0.6 has no lax.pvary (no varying-manual-axes tracking) — identity is
-# the correct degenerate form there
-_pvary = getattr(lax, "pvary", lambda x, axes: x)
 
 
 def pipelined_forward(mesh: Mesh, stage_fn: Callable, params_stacked: Any,
@@ -45,9 +40,9 @@ def pipelined_forward(mesh: Mesh, stage_fn: Callable, params_stacked: Any,
         stage = lax.axis_index("pod")
         # registers must be marked pod-varying up-front so scan/cond branches
         # agree on the manual-axes type (shard_map vma rules)
-        state = _pvary(jnp.zeros_like(xs[0]), ("pod",))
-        outputs = _pvary(jnp.zeros_like(xs), ("pod",))
-        xs = _pvary(xs, ("pod",))
+        state, outputs, xs = lax.pcast(
+            (jnp.zeros_like(xs[0]), jnp.zeros_like(xs), xs), ("pod",),
+            to="varying")
 
         def tick(carry, t):
             state, outputs = carry
@@ -79,8 +74,8 @@ def pipelined_forward(mesh: Mesh, stage_fn: Callable, params_stacked: Any,
                 P(None, None, None, None))
     # the trailing ppermute broadcast makes every pod hold identical outputs,
     # but the vma type system can't infer that replication -> check off
-    fn = shard_map(local, mesh=mesh, in_specs=in_specs,
-                   out_specs=P(None, None, None, None), check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                       out_specs=P(None, None, None, None), check_vma=False)
     return fn(layers, x_micro)
 
 

@@ -16,11 +16,43 @@ from __future__ import annotations
 
 from typing import Optional
 
+import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas import tpu as pltpu
 
 #: Backends whose Pallas lowering is compiled (Mosaic / Triton).
 COMPILED_BACKENDS = ("tpu", "gpu")
+
+#: Scoped VMEM one kernel may use, in bytes. A TPU v5e TensorCore has
+#: 128 MiB of VMEM (Google Cloud, "TPU v5e" system architecture); the
+#: compiler's default scope is 16 MiB of it. Every ``pallas_call`` passes
+#: this limit (`compiler_params`) and the megakernel's block autotuner sizes
+#: against the same number, so tuner and compiler agree.
+VMEM_LIMIT_BYTES = 16 * 2 ** 20
+
+#: Lane width of a TPU vreg: the minor dimension of every VMEM buffer is
+#: padded to a multiple of it (layout ``T(8,128)`` for 32-bit types).
+LANES = 128
+
+
+def compiler_params():
+    """The Mosaic compiler parameters every kernel of this package uses."""
+    return pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
+
+
+def tiled_bytes(shape, dtype) -> int:
+    """VMEM bytes of a buffer of ``shape`` as the TPU lays it out: the minor
+    dimension padded to 128 lanes, the second-minor to the dtype's sublane
+    tile (8 rows of 32 bits, so 16 for 16-bit and 32 for 8-bit types)."""
+    item = np.dtype(dtype).itemsize
+    dims = list(shape)
+    if dims:
+        dims[-1] = -(-dims[-1] // LANES) * LANES
+    if len(dims) >= 2:
+        sub = 8 * max(1, 4 // item)
+        dims[-2] = -(-dims[-2] // sub) * sub
+    return int(np.prod(dims, dtype=np.int64)) * item
 
 
 def default_interpret() -> bool:

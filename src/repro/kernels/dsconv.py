@@ -14,8 +14,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.bsconv import _dw3x3
-from repro.kernels.dispatch import pad_batch, resolve_block, resolve_interpret
+from repro.kernels.bsconv import _dw3x3, _pw_dot
+from repro.kernels.dispatch import (compiler_params, pad_batch, resolve_block,
+                                    resolve_interpret)
 
 
 def dsconv_kernel(x_ref, dw_ref, dwb_ref, pw_ref, pwb_ref, o_ref, *, relu: bool):
@@ -23,8 +24,7 @@ def dsconv_kernel(x_ref, dw_ref, dwb_ref, pw_ref, pwb_ref, o_ref, *, relu: bool)
     b, h, w, cin = x.shape
     cout = pw_ref.shape[-1]
     y = _dw3x3(x, dw_ref[...]) + dwb_ref[...]
-    y = jnp.dot(y.reshape(b * h * w, cin), pw_ref[...],
-                preferred_element_type=jnp.float32) + pwb_ref[...]
+    y = _pw_dot(y.reshape(b * h * w, cin), pw_ref[...]) + pwb_ref[...]
     if relu:
         y = jnp.maximum(y, 0.0)
     o_ref[...] = y.reshape(b, h, w, cout).astype(o_ref.dtype)
@@ -57,4 +57,5 @@ def dsconv_fused(x, dw, dw_b, pw, pw_b, *, relu: bool = False,
         out_specs=pl.BlockSpec((bblk, h, w, cout), lambda i: (i, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((x.shape[0], h, w, cout), x.dtype),
         interpret=interpret,
+        compiler_params=compiler_params(),
     )(x, dw, dw_b.reshape(1, cin), pw, pw_b.reshape(1, cout))[:n]

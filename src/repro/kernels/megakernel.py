@@ -28,11 +28,12 @@ Two datapaths, selected by ``ExecutionPlan(fusion="group")``:
 
 Block sizing is the roofline-driven ``autotune_block_patches``: the fused
 group's arithmetic intensity (MACs per streamed feature byte) is fixed by
-the model, so the block is the largest patch count whose live VMEM working
-set (weights + staged block + scratch feature + output block, double
-buffered) fits the per-core budget, floored so the pointwise matmuls keep
-full MXU rows. `launch/roofline.py`'s hardware constants decide which side
-of the ridge the fused group lands on (reported by ``autotune_report``).
+the model, so the block is the largest patch count whose VMEM working set,
+tiled as the chip lays it out (weights, input and output blocks double
+buffered, scratch feature, body temporaries), fits the scoped-VMEM limit
+every kernel passes to the compiler (`kernels.dispatch.VMEM_LIMIT_BYTES`).
+`launch/roofline.py`'s hardware constants decide which side of the ridge
+the fused group lands on (reported by ``autotune_report``).
 """
 from __future__ import annotations
 
@@ -44,8 +45,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.bsconv import _dw3x3
-from repro.kernels.dispatch import pad_batch, resolve_block, resolve_interpret
+from repro.kernels.bsconv import _dw3x3, _pw_dot
+from repro.kernels.dispatch import (VMEM_LIMIT_BYTES, compiler_params,
+                                    pad_batch, resolve_block,
+                                    resolve_interpret, tiled_bytes)
 from repro.kernels.qconv import (_qbsconv_math, _qdsconv_math, _qsfb_math,
                                  _quantize_math, _sfb_consts, prepare_qparams)
 from repro.launch.roofline import HBM_BW, PEAK_FLOPS
@@ -60,48 +63,64 @@ from repro.quant.pams import QuantPack, code_dtype
 # roofline-driven block-size autotuner (static — shapes and dtypes only)
 # ---------------------------------------------------------------------------
 
-#: Per-core VMEM budget (v5e-class, launch/roofline.py's hardware family).
-VMEM_BYTES = 16 * 2 ** 20
-
 #: MXU systolic array rows: pointwise matmuls want at least this many rows
 #: per grid step, or the array runs partially empty.
 _MXU_ROWS = 256
 
+#: Feature-sized fp32 values the kernel body keeps live besides its scratch
+#: (pointwise outputs, the padded depthwise input, its accumulator; the
+#: integer body also holds dequantized inputs and int32 accumulators).
+#: Calibrated against the v5e compiler's scoped-VMEM report for 32x32
+#: patches in a 4096-patch batch: fp32 needs 4.54/9.27/13.94/18.59 MiB at
+#: blocks 1/2/3/4, int8 12.04/16.57 MiB at blocks 3/4 (short grids need
+#: less) — the model stays above both.
+_BODY_TEMPS = {False: 5, True: 7}
+
 
 def _group_weight_bytes(width: int, n_sfb: int, out_channels: int,
                         in_channels: int = 3) -> int:
-    """fp32 bytes of every stationary operand the fused group keeps in VMEM
-    (weights + biases/scales; the quant variants are smaller, so sizing by
-    fp32 is the conservative bound)."""
+    """Tiled fp32 VMEM bytes of every stationary operand the fused group
+    keeps resident (weights + biases/scales, each padded to the (8, 128)
+    tile; the quant variants are no larger, so fp32 is the bound)."""
     c = width
-    first = in_channels * c + c + 9 * c + c
-    sfb = 2 * (c * c + c + 9 * c + c) + c * c + c
-    recon = 9 * c + c + c * out_channels + out_channels
-    return 4 * (first + n_sfb * sfb + recon)
+    row, dw = tiled_bytes((1, c), jnp.float32), tiled_bytes((3, 3, c),
+                                                            jnp.float32)
+    pw = lambda cin, cout: tiled_bytes((cin, cout), jnp.float32)
+    first = pw(in_channels, c) + row + dw + row
+    sfb = 2 * (pw(c, c) + row + dw + row) + pw(c, c) + row
+    recon = (dw + row + pw(c, out_channels)
+             + tiled_bytes((1, out_channels), jnp.float32))
+    return first + n_sfb * sfb + recon
 
 
 def autotune_report(width: int, patch: int, scale: int, n_sfb: int = 5,
                     *, in_channels: int = 3,
-                    vmem_bytes: int = VMEM_BYTES) -> Dict[str, Any]:
+                    quant: bool = False) -> Dict[str, Any]:
     """Static roofline sizing of the fused group at one (width, patch) point.
 
     The streamed HBM traffic per patch is fixed (input block in, SR block
     out — intermediates never leave VMEM), so arithmetic intensity does not
-    depend on the block size; what the block controls is VMEM occupancy
-    (upper bound: weights + staged input + scratch feature + output, double
-    buffered into half the budget) and MXU row utilization (lower bound:
-    ``block * patch^2 >= 256`` rows). The tuner takes the largest block in
-    that feasible band."""
+    depend on the block size; what the block controls is VMEM occupancy and
+    MXU row utilization (lower bound: ``block * patch^2 >= 256`` rows). The
+    tuner takes the largest block in that feasible band.
+
+    VMEM is counted as the chip lays it out (`tiled_bytes`: channels padded
+    to 128 lanes): the double-buffered weights, and per patch the
+    double-buffered input and output blocks, the scratch feature and the
+    body's live temporaries, against ``VMEM_LIMIT_BYTES`` — the same limit
+    every ``pallas_call`` passes to the compiler. ``quant`` sizes the
+    integer kernel (int8 codes in scratch and output, more temporaries)."""
     out_channels = in_channels * scale * scale
-    weight_b = _group_weight_bytes(width, n_sfb, out_channels, in_channels)
-    # live per-patch VMEM: staged input + scratch feature + one wide SFB
-    # temporary + pre-shuffle output, all fp32
-    per_patch_b = 4 * patch * patch * (in_channels + 2 * width + out_channels)
-    budget = max(0, vmem_bytes // 2 - weight_b)
-    vmem_cap = max(1, budget // max(1, per_patch_b))
+    code = jnp.int8 if quant else jnp.float32
+    tile = lambda c, dt: tiled_bytes((patch, patch, c), dt)
+    weight_b = 2 * _group_weight_bytes(width, n_sfb, out_channels,
+                                       in_channels)
+    per_patch_b = (2 * tile(in_channels, jnp.float32)
+                   + 2 * tile(out_channels, code) + tile(width, code)
+                   + _BODY_TEMPS[quant] * tile(width, jnp.float32))
+    vmem_cap = max(0, VMEM_LIMIT_BYTES - weight_b) // per_patch_b
     mxu_floor = max(1, -(-_MXU_ROWS // (patch * patch)))
-    block = max(mxu_floor, vmem_cap)
-    block = min(block, 512)                      # grid-step sanity ceiling
+    block = max(1, min(vmem_cap, 512))           # grid-step sanity ceiling
     macs_pp = essr_macs_per_lr_pixel(
         ESSRConfig(channels=width, n_sfb=n_sfb, scale=scale,
                    in_channels=in_channels)) * patch * patch
@@ -112,8 +131,9 @@ def autotune_report(width: int, patch: int, scale: int, n_sfb: int = 5,
         "block_patches": int(block),
         "weight_bytes": int(weight_b),
         "per_patch_bytes": int(per_patch_b),
-        "vmem_budget_bytes": int(vmem_bytes),
+        "vmem_budget_bytes": VMEM_LIMIT_BYTES,
         "mxu_row_floor": int(mxu_floor),
+        "fits_vmem": weight_b + block * per_patch_b <= VMEM_LIMIT_BYTES,
         "arith_intensity_mac_per_byte": float(intensity),
         "roofline_ridge_mac_per_byte": float(ridge),
         "bound": "compute" if intensity >= ridge else "memory",
@@ -123,11 +143,11 @@ def autotune_report(width: int, patch: int, scale: int, n_sfb: int = 5,
 @functools.lru_cache(maxsize=256)
 def autotune_block_patches(width: int, patch: int, scale: int,
                            n_sfb: int = 5, *, in_channels: int = 3,
-                           vmem_bytes: int = VMEM_BYTES) -> int:
+                           quant: bool = False) -> int:
     """The block size `autotune_report` picks (cached — pure shape math)."""
     return autotune_report(width, patch, scale, n_sfb,
                            in_channels=in_channels,
-                           vmem_bytes=vmem_bytes)["block_patches"]
+                           quant=quant)["block_patches"]
 
 
 # ---------------------------------------------------------------------------
@@ -197,8 +217,7 @@ def _mega_kernel(*refs, n_sfb: int):
 
     def bs(v, pw, pwb, dw, dwb):
         c_in = v.shape[-1]
-        y = jnp.dot(v.reshape(b * h * w, c_in), pw,
-                    preferred_element_type=jnp.float32)
+        y = _pw_dot(v.reshape(b * h * w, c_in), pw)
         y = (y + pwb).reshape(b, h, w, -1)
         return _dw3x3(y, dw) + dwb
 
@@ -213,14 +232,12 @@ def _mega_kernel(*refs, n_sfb: int):
         y = jnp.maximum(bs(xin, *b1), 0.0)
         y = jnp.maximum(bs(y, *b2), 0.0)
         y = y + xin                                  # shortcut adder
-        y = jnp.dot(y.reshape(b * h * w, c), fuse,
-                    preferred_element_type=jnp.float32) + fuseb
+        y = _pw_dot(y.reshape(b * h * w, c), fuse) + fuseb
         feat_ref[...] = jnp.maximum(y, 0.0).reshape(b, h, w, c)
     rdw, rdwb, rpw, rpwb = take(4)
     f = feat_ref[...]
     y = _dw3x3(f, rdw) + rdwb
-    y = jnp.dot(y.reshape(b * h * w, f.shape[-1]), rpw,
-                preferred_element_type=jnp.float32) + rpwb
+    y = _pw_dot(y.reshape(b * h * w, f.shape[-1]), rpw) + rpwb
     o_ref[...] = y.reshape(b, h, w, -1).astype(o_ref.dtype)
 
 
@@ -268,6 +285,7 @@ def _mega_forward(params, x, cfg: ESSRConfig, width: int,
         out_shape=jax.ShapeDtypeStruct((x.shape[0], h, w, cout), x.dtype),
         scratch_shapes=[pltpu.VMEM((bblk, h, w, width), jnp.float32)],
         interpret=interp,
+        compiler_params=compiler_params(),
     )(x, *wops)
     return pixel_shuffle(up, cfg.scale)[:n]
 
@@ -379,7 +397,7 @@ def essr_forward_qmegakernel(params, x, cfg: ESSRConfig,
     q, c = prepare_qparams(params, cfg, w, pack)
     bp = block_patches if block_patches is not None else \
         autotune_block_patches(w, int(x.shape[1]), cfg.scale, cfg.n_sfb,
-                               in_channels=cfg.in_channels)
+                               in_channels=cfg.in_channels, quant=True)
     bblk = resolve_block(x.shape[0], bp)
     x, n = pad_batch(x, bblk)
     _, h, wdim, cin = x.shape
@@ -400,10 +418,11 @@ def essr_forward_qmegakernel(params, x, cfg: ESSRConfig,
         out_shape=jax.ShapeDtypeStruct((x.shape[0], h, wdim, cout), cdt),
         scratch_shapes=[pltpu.VMEM((bblk, h, wdim, w), cdt)],
         interpret=interp,
+        compiler_params=compiler_params(),
     )(x, *wops)
     up = r.astype(jnp.float32) * c["s_recon"]         # single dequant
     return pixel_shuffle(up, cfg.scale)[:n]
 
 
 __all__ = ["essr_forward_megakernel", "essr_forward_qmegakernel",
-           "autotune_block_patches", "autotune_report", "VMEM_BYTES"]
+           "autotune_block_patches", "autotune_report"]

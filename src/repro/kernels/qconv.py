@@ -39,8 +39,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.bsconv import _dw3x3
-from repro.kernels.dispatch import pad_batch, resolve_block, resolve_interpret
+from repro.kernels.bsconv import _dw3x3, _pw_dot
+from repro.kernels.dispatch import (compiler_params, pad_batch, resolve_block,
+                                    resolve_interpret)
 from repro.models.essr import ESSRConfig, slice_width
 from repro.models.layers import pixel_shuffle
 from repro.quant.pams import (EPS, QuantPack, code_dtype, step_size,
@@ -127,8 +128,7 @@ def _qdsconv_math(xq, dwq, dw_scale, dw_b, pw_fq, pw_b, *, a_out: float,
     b, h, w, cin = xq.shape
     acc = _dw3x3_i32(xq.astype(jnp.int32), dwq)
     y = acc.astype(jnp.float32) * dw_scale + dw_b
-    y = jnp.dot(y.reshape(b * h * w, cin), pw_fq,
-                preferred_element_type=jnp.float32) + pw_b
+    y = _pw_dot(y.reshape(b * h * w, cin), pw_fq) + pw_b
     y = y.reshape(b, h, w, -1)
     return _quantize_math(y, a_out, s_out, xq.dtype)
 
@@ -160,6 +160,7 @@ def quantize_fused(x, *, a: float, s: float, bits: int,
         out_specs=pl.BlockSpec((bblk,) + shp, lambda i: (i,) + (0,) * len(shp)),
         out_shape=jax.ShapeDtypeStruct(x.shape, code_dtype(bits)),
         interpret=interpret,
+        compiler_params=compiler_params(),
     )(x)[:n]
 
 
@@ -199,6 +200,7 @@ def qbsconv_fused(xq, pwq, pw_scale, pw_b, dw_fq, dw_b, *, relu: bool,
         out_specs=pl.BlockSpec((bblk, h, w, cout), lambda i: (i, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((xq.shape[0], h, w, cout), xq.dtype),
         interpret=interpret,
+        compiler_params=compiler_params(),
     )(xq, pwq, pw_scale.reshape(1, cout), pw_b.reshape(1, cout), dw_fq,
       dw_b.reshape(1, cout))[:n]
 
@@ -252,6 +254,7 @@ def qsfb_fused(xq, q: Dict[str, jax.Array], *, consts: Tuple[float, ...],
         out_specs=pl.BlockSpec((bblk, h, w, c), lambda i: (i, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((xq.shape[0], h, w, c), xq.dtype),
         interpret=interpret,
+        compiler_params=compiler_params(),
     )(xq, q["b1_pwq"], r2(q["b1_pw_scale"]), r2(q["b1_pwb"]), q["b1_dw_fq"],
       r2(q["b1_dwb"]), q["b2_pwq"], r2(q["b2_pw_scale"]), r2(q["b2_pwb"]),
       q["b2_dw_fq"], r2(q["b2_dwb"]), q["fuseq"], r2(q["fuse_scale_y"]),
@@ -293,6 +296,7 @@ def qdsconv_fused(xq, dwq, dw_scale, dw_b, pw_fq, pw_b, *, a_out: float,
         out_specs=pl.BlockSpec((bblk, h, w, cout), lambda i: (i, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((xq.shape[0], h, w, cout), xq.dtype),
         interpret=interpret,
+        compiler_params=compiler_params(),
     )(xq, dwq, dw_scale.reshape(1, cin), dw_b.reshape(1, cin), pw_fq,
       pw_b.reshape(1, cout))[:n]
 

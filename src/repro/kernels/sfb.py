@@ -15,8 +15,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.bsconv import _dw3x3
-from repro.kernels.dispatch import pad_batch, resolve_block, resolve_interpret
+from repro.kernels.bsconv import _dw3x3, _pw_dot
+from repro.kernels.dispatch import (compiler_params, pad_batch, resolve_block,
+                                    resolve_interpret)
 
 
 def sfb_kernel(x_ref, b1pw_ref, b1pwb_ref, b1dw_ref, b1dwb_ref,
@@ -26,15 +27,14 @@ def sfb_kernel(x_ref, b1pw_ref, b1pwb_ref, b1dw_ref, b1dwb_ref,
     b, h, w, c = x.shape
 
     def bs(v, pw, pwb, dw, dwb):
-        y = jnp.dot(v.reshape(b * h * w, c), pw, preferred_element_type=jnp.float32)
+        y = _pw_dot(v.reshape(b * h * w, c), pw)
         y = (y + pwb).reshape(b, h, w, c)
         return _dw3x3(y, dw) + dwb
 
     y = jnp.maximum(bs(x, b1pw_ref[...], b1pwb_ref[...], b1dw_ref[...], b1dwb_ref[...]), 0.0)
     y = jnp.maximum(bs(y, b2pw_ref[...], b2pwb_ref[...], b2dw_ref[...], b2dwb_ref[...]), 0.0)
     y = y + x                                            # shortcut adder
-    y = jnp.dot(y.reshape(b * h * w, c), fuse_ref[...],
-                preferred_element_type=jnp.float32) + fuseb_ref[...]
+    y = _pw_dot(y.reshape(b * h * w, c), fuse_ref[...]) + fuseb_ref[...]
     o_ref[...] = jnp.maximum(y, 0.0).reshape(b, h, w, c).astype(o_ref.dtype)
 
 
@@ -66,6 +66,7 @@ def sfb_fused(x, p, *, block_patches: int = 4, interpret: Optional[bool] = None)
         out_specs=pl.BlockSpec((bblk, h, w, c), lambda i: (i, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((x.shape[0], h, w, c), x.dtype),
         interpret=interpret,
+        compiler_params=compiler_params(),
     )(x, p["b1_pw"], r2(p["b1_pwb"]), p["b1_dw"], r2(p["b1_dwb"]),
       p["b2_pw"], r2(p["b2_pwb"]), p["b2_dw"], r2(p["b2_dwb"]),
       p["fuse"], r2(p["fuse_b"]))[:n]

@@ -5,17 +5,11 @@ state; the dry-run sets XLA_FLAGS before any jax import (dryrun.py)."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-try:                                    # jax >= 0.5: explicit axis types
-    from jax.sharding import AxisType
 
-    def _axis_kw(n: int):
-        return {"axis_types": (AxisType.Auto,) * n}
-except ImportError:                     # older jax: every axis is Auto already
-    AxisType = None
-
-    def _axis_kw(n: int):
-        return {}
+def _axis_kw(n: int):
+    return {"axis_types": (AxisType.Auto,) * n}
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -49,6 +49,8 @@ def main():
                          "frame N+1's ingest (one-frame control delay)")
     args = ap.parse_args()
 
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     from repro.api import ExecutionPlan, SREngine
     from repro.core.adaptive import SwitchingConfig
     from repro.data.synthetic import degrade, random_image

@@ -14,7 +14,7 @@ never crash.  Three pieces live here:
   degradation ledger can be asserted bit-for-bit in CI.
 * `ResilienceGuard` — the **degradation ladder**.  From the configured
   serving point it precomputes the deterministic step-down order
-  (fusion ``group→layer``, backend ``pallas→interpret→ref``, quant
+  (fusion ``group→layer``, backend ``pallas→ref``, quant
   ``int8/fxp10→fp32``); on a failed launch it steps down (or retries at the
   floor) up to ``plan.max_retries`` times, recording every step.  The
   ladder is *sticky*: later frames serve at the degraded level.
@@ -221,10 +221,12 @@ def build_ladder(backend: str, interpret: Optional[bool], quant_on: bool,
     """Deterministic step-down order from the configured serving point.
 
     Order (each step only present when it changes something):
-    fusion ``group→layer``, backend ``pallas→interpret``, backend
-    ``→ref``, quant ``int8/fxp10→fp32``.  The last rung is always the
-    ref/fp32/layer floor; a failure there (retried up to
-    ``plan.max_retries`` total attempts) propagates to the caller.
+    fusion ``group→layer``, backend ``→ref``, quant ``int8/fxp10→fp32``.
+    A compiled Pallas deployment steps straight to ``ref``: the Pallas
+    interpreter cannot meet any deadline on a chip, so it is never a rung.
+    The last rung is always the ref/fp32/layer floor; a failure there
+    (retried up to ``plan.max_retries`` total attempts) propagates to the
+    caller.
     """
     rungs = [LadderVariant(backend, interpret, quant_on, fusion)]
 
@@ -243,8 +245,6 @@ def build_ladder(backend: str, interpret: Optional[bool], quant_on: bool,
 
     if fusion == "group":
         push("fusion:group->layer", fusion="layer")
-    if backend == "pallas" and interpret is not True:
-        push("backend:pallas->interpret", interpret=True)
     if backend != "ref":
         push("backend:->ref", backend="ref", interpret=None)
     if quant_on:

@@ -51,7 +51,7 @@ def test_essr102_weak_typed_output_detected():
 
 
 def test_essr102_wide_dtype_detected():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         closed = jax.make_jaxpr(
             lambda x: x.astype(jnp.float64) * 2.0
         )(jnp.ones((4,), jnp.float32))

@@ -130,7 +130,7 @@ def test_pipeline_matches_seed_loop_reference(engine, lr_frame):
                             use_loop_reference=True)
     assert new.ids.tolist() == old.ids.tolist()
     np.testing.assert_allclose(np.asarray(new.image), np.asarray(old.image),
-                               atol=1e-5)
+                               rtol=1e-6, atol=1e-5)
 
 
 # -- upscale modes + ids_override round-trip ---------------------------------

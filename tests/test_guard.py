@@ -192,15 +192,16 @@ def test_injector_deterministic_across_instances():
 def test_degradation_ladder_order():
     steps = [v.step for v in build_ladder("pallas", False, True, "group")]
     # the first rung is the as-planned serving point (empty step label)
-    assert steps == ["", "fusion:group->layer",
-                     "backend:pallas->interpret", "backend:->ref",
+    assert steps == ["", "fusion:group->layer", "backend:->ref",
                      "quant:->fp32"]
     # the floor plan has no rungs below it
     assert [v.step for v in build_ladder("ref", False, False, "layer")] \
         == [""]
-    # interpret-resolved pallas skips the interpret rung
-    assert "backend:pallas->interpret" not in [
-        v.step for v in build_ladder("pallas", True, False, "layer")]
+    # no rung ever serves through the Pallas interpreter: compiled pallas
+    # steps straight to ref
+    for interp in (None, False, True):
+        assert [v.step for v in build_ladder("pallas", interp, False,
+                                             "layer")] == ["", "backend:->ref"]
 
 
 def test_injected_backend_failures_degrade_deterministically():

@@ -27,8 +27,8 @@ import pytest
 from repro.api import ExecutionPlan, SREngine
 from repro.core.caching import BoundedCache, bounded_cache
 from repro.data.synthetic import degrade, random_image
-from repro.kernels.dispatch import pad_batch, resolve_block
-from repro.kernels.megakernel import (VMEM_BYTES, autotune_block_patches,
+from repro.kernels.dispatch import VMEM_LIMIT_BYTES, pad_batch, resolve_block
+from repro.kernels.megakernel import (autotune_block_patches,
                                       autotune_report,
                                       essr_forward_megakernel,
                                       essr_forward_qmegakernel)
@@ -192,9 +192,9 @@ def test_autotune_block_bounds():
     rep = autotune_report(54, 32, 4)
     bp = rep["block_patches"]
     assert bp == autotune_block_patches(54, 32, 4)
-    # VMEM ceiling: weights + double-buffered feature block fit the budget
-    assert rep["weight_bytes"] + 2 * bp * rep["per_patch_bytes"] \
-        <= VMEM_BYTES or bp == rep["mxu_row_floor"]
+    # VMEM ceiling: the tiled weights + per-patch working set fit the budget
+    assert rep["weight_bytes"] + bp * rep["per_patch_bytes"] \
+        <= VMEM_LIMIT_BYTES and rep["fits_vmem"]
     # MXU floor: the flattened (block*p*p, C) operand keeps the rows full
     assert bp * 32 * 32 >= 256
     assert rep["bound"] in ("memory", "compute")

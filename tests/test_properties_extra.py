@@ -16,7 +16,10 @@ def test_lamb_update_invariant_to_gradient_scale(scale, seed):
     lets the paper train at batch 256 / lr 3e-3."""
     key = jax.random.PRNGKey(seed)
     params = {"w": jax.random.normal(key, (8, 8)) + 2.0}
-    grads = {"w": jax.random.normal(jax.random.PRNGKey(seed + 1), (8, 8))}
+    # entries kept >= 0.1 in magnitude: Adam's eps (1e-6) breaks the
+    # invariance for entries near 1e-3, which ~3% of seeds draw
+    n = jax.random.normal(jax.random.PRNGKey(seed + 1), (8, 8))
+    grads = {"w": jnp.sign(n) * (0.1 + jnp.abs(n))}
     opt = O.lamb(0.1)
 
     def one_update(g):
