@@ -24,8 +24,9 @@ error bound printed with it, run compiled (no ``-interpret`` label) and
 record no step down the degradation ladder. Any failure raises, so the
 script exits nonzero; it prints its JSON verdict only as the very last
 line of a run that passed. It needs a TPU: with no accelerator visible it
-exits nonzero before any phase. Seconds and ms/frame it prints are
-informational, not benchmark metrics.
+exits nonzero before any phase. It serves each phase's frames once and
+times nothing but set-up and the reference; the benchmark (`bench/`)
+measures speed.
 """
 from __future__ import annotations
 
@@ -114,21 +115,9 @@ def check_close(name: str, res, ref, bound: float) -> None:
     assert err <= bound, f"{name}: error {err} exceeds {bound}"
 
 
-def timed_upscale(eng, frame, reps: int = 3):
-    """(result, first-call seconds incl. compile, steady ms/frame)."""
-    t0 = time.perf_counter()
-    res = eng.upscale(frame)
-    first = time.perf_counter() - t0
-    t0 = time.perf_counter()
-    for _ in range(reps):
-        eng.upscale(frame)
-    return res, first, (time.perf_counter() - t0) / reps * 1e3
-
-
-def report(name: str, res, first: float, steady_ms: float) -> None:
-    print(f"  {name}: counts (bilinear, C27, C54) = {res.counts}; "
-          f"informational: first frame {first:.1f} s incl. compile, "
-          f"steady {steady_ms:.1f} ms/frame", flush=True)
+def report(name: str, res) -> None:
+    print(f"  {name}: counts (bilinear, C27, C54) = {res.counts}",
+          flush=True)
 
 
 def phase_reference(frames):
@@ -148,10 +137,10 @@ def phase_reference(frames):
 
 def phase_host(name: str, plan, frame, ref):
     eng = engine(plan)
-    res, first, steady = timed_upscale(eng, frame)
+    res = eng.upscale(frame)
     print(f"{name} pallas, fusion={plan.fusion}, dispatch={plan.dispatch}",
           flush=True)
-    report(name, res, first, steady)
+    report(name, res)
     check_served(name, eng, [res], "pallas")
     check_routing(name, res, ref)
     check_close(name, res, ref, BOUND_FP32)
@@ -170,15 +159,10 @@ def phase_fused_stream(frames, refs):
     plan = plan.replace(capacity=caps)
     eng = engine(plan, switching=SwitchingConfig(
         frame_high=10 ** 9, frame_low=0, c54_per_sec_budget=10 ** 9))
-    t0 = time.perf_counter()
-    results, stamps = [], []
-    for res in eng.stream(frames):
-        results.append(res)
-        stamps.append(time.perf_counter())
+    results = list(eng.stream(frames))
     print(f"(c) pallas, fusion=layer, dispatch=fused, inflight=2, "
           f"capacity={caps}, {len(frames)} frames streamed", flush=True)
-    report("(c)", results[0], stamps[0] - t0,
-           (stamps[-1] - stamps[0]) / (len(stamps) - 1) * 1e3)
+    report("(c)", results[0])
     assert len(results) == len(frames)
     check_served("(c)", eng, results, "pallas")
     for i, (res, ref) in enumerate(zip(results, refs)):
@@ -221,9 +205,9 @@ def phase_int8(frame):
     eng = engine(ExecutionPlan(interpret=False, fusion="group",
                                quant="int8"))
     assert eng.qpack == ref_eng.qpack, "(e) calibrations differ"
-    res, first, steady = timed_upscale(eng, frame)
+    res = eng.upscale(frame)
     print("(e) pallas-int8, fusion=group, dispatch=host", flush=True)
-    report("(e)", res, first, steady)
+    report("(e)", res)
     check_served("(e)", eng, [res], "pallas-int8")
     check_routing("(e)", res, ref)
     step = max(act_qconsts(eng.qpack.act_scales(w)["recon"],
@@ -276,8 +260,8 @@ def run_four_chips() -> None:
         # the same TPU platform `require_devices` found
         assert {d.platform for d in mesh.devices.flat} == {
             jax.devices()[0].platform}
-        res, first, steady = timed_upscale(eng, frame)
-        report(name, res, first, steady)
+        res = eng.upscale(frame)
+        report(name, res)
         check_served(name, eng, [res], "pallas")
         check_routing(name, res, one)
         check_close(name, res, one, BOUND_SHARDED)

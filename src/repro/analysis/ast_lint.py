@@ -61,6 +61,7 @@ TRACER_CALLS = frozenset({
     "jit", "pallas_call", "shard_map", "vmap", "pmap", "scan", "while_loop",
     "cond", "switch", "remat", "checkpoint", "custom_jvp", "custom_vjp",
     "grad", "value_and_grad", "make_jaxpr", "eval_shape", "named_call",
+    "phase_jit",
 })
 
 #: Annotation tokens that sink a frozen dataclass's hashability (ESSR205).

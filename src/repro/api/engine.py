@@ -54,6 +54,7 @@ from repro.core import subnet_policy as sp
 from repro.core.adaptive import (AdaptiveSwitcher, ShardSwitcherBank,
                                  StreamSwitcherBank, SwitchingConfig)
 from repro.core.edge_score import edge_score
+from repro.core.phases import frame_span, span
 from repro.core.pipeline import (compiled_cache_occupancy,
                                  configure_compiled_caches,
                                  edge_selective_sr, frame_health,
@@ -288,17 +289,20 @@ class SREngine:
         Returns (frame, health tuple or None, route-to-bilinear flag)."""
         if p.on_poison == "off":
             return frame, None, False
-        health_t = tuple(int(c) for c in np.asarray(frame_health(frame)))
-        if not any(health_t):
-            return frame, health_t, False
-        self.guard.record(index, "poison",
-                          f"frame health nan/inf/oob={health_t} "
-                          f"(policy {p.on_poison})")
-        if p.on_poison == "raise":
-            raise PoisonFrameError(
-                f"frame failed health verdict nan/inf/oob={health_t} "
-                f"(plan.on_poison='raise')", health=health_t)
-        return sanitize_frame(frame), health_t, p.on_poison == "bilinear"
+        with span("essr.health"):
+            health = frame_health(frame)
+            with span("essr.wait.health"):
+                health_t = tuple(int(c) for c in np.asarray(health))
+            if not any(health_t):
+                return frame, health_t, False
+            self.guard.record(index, "poison",
+                              f"frame health nan/inf/oob={health_t} "
+                              f"(policy {p.on_poison})")
+            if p.on_poison == "raise":
+                raise PoisonFrameError(
+                    f"frame failed health verdict nan/inf/oob={health_t} "
+                    f"(plan.on_poison='raise')", health=health_t)
+            return sanitize_frame(frame), health_t, p.on_poison == "bilinear"
 
     def _guarded_frames(self, frames: Iterable, stream_id: int = 0,
                         ) -> Iterator:
@@ -388,8 +392,16 @@ class SREngine:
                 # probe on the sanitized frame: a poisoned first frame must
                 # not seed a garbage capacity profile for its whole geometry
                 frame = sanitize_frame(frame)
-            scores = np.asarray(edge_score(geom.extract(frame)))
-            counts = sp.subnet_counts(sp.decide(scores, t1, t2))
+            with span("essr.extract"):
+                patches = geom.extract(frame)
+            with span("essr.route"):
+                scores = edge_score(patches)
+                with span("essr.wait.scores"):
+                    scores = np.asarray(scores)
+                ids = sp.decide(scores, t1, t2)
+                with span("essr.wait.route"):
+                    ids = np.asarray(ids)
+            counts = sp.subnet_counts(ids)
             caps = self._snap_profile(counts, geom, p)
             self._fused_caps[key] = caps
         if streaming:
@@ -426,32 +438,34 @@ class SREngine:
         dispatch), so frame N+1's ingest overlaps frame N's compute."""
         t0 = time.perf_counter()
         index = self._next_index()
-        frame = self._ingest_frame(frame, p, index)
-        geom = p.geometry(frame.shape[0], frame.shape[1], self.cfg.scale)
-        caps = self._fused_caps_for(geom, p, frame, thresholds, streaming)
-        if self.injector is not None:
-            self.injector.maybe_delay(index)
-        t1, t2 = thresholds
-
-        def attempt(v):
+        with frame_span("essr.launch", index):
+            frame = self._ingest_frame(frame, p, index)
+            geom = p.geometry(frame.shape[0], frame.shape[1], self.cfg.scale)
+            caps = self._fused_caps_for(geom, p, frame, thresholds, streaming)
             if self.injector is not None:
-                self.injector.maybe_fail_launch(index)
-            fn = fused_frame_fn(geom, caps, self.cfg, v.backend, v.interpret,
-                                self.mesh, self.qpack if v.quant else None,
-                                v.fusion, p.on_poison)
-            return fn(self.params, frame, t1, t2)
+                self.injector.maybe_delay(index)
+            t1, t2 = thresholds
 
-        # the degradation ladder owns retries: a failed launch (injected or
-        # genuine) steps down fusion -> ref -> fp32 and re-runs
-        outs, steps = self.guard.run(attempt, index)
-        v = self.guard.variant
-        compiled = self._mark_warm(("fused", geom.cache_key, caps,
-                                    v.backend, v.interpret, v.quant,
-                                    v.fusion, p.on_poison))
-        return {"outs": outs, "geom": geom, "caps": caps, "t0": t0,
-                "plan": p, "thresholds": (t1, t2), "compiled": compiled,
-                "streaming": streaming, "variant": v, "steps": steps,
-                "index": index}
+            def attempt(v):
+                if self.injector is not None:
+                    self.injector.maybe_fail_launch(index)
+                fn = fused_frame_fn(geom, caps, self.cfg, v.backend,
+                                    v.interpret, self.mesh,
+                                    self.qpack if v.quant else None,
+                                    v.fusion, p.on_poison)
+                return fn(self.params, frame, t1, t2)
+
+            # the degradation ladder owns retries: a failed launch (injected
+            # or genuine) steps down fusion -> ref -> fp32 and re-runs
+            outs, steps = self.guard.run(attempt, index)
+            v = self.guard.variant
+            compiled = self._mark_warm(("fused", geom.cache_key, caps,
+                                        v.backend, v.interpret, v.quant,
+                                        v.fusion, p.on_poison))
+            return {"outs": outs, "geom": geom, "caps": caps, "t0": t0,
+                    "plan": p, "thresholds": (t1, t2), "compiled": compiled,
+                    "streaming": streaming, "variant": v, "steps": steps,
+                    "index": index}
 
     def _finalize_fused(self, rec: dict) -> FrameResult:
         """Block on one in-flight fused frame, materialize its routing
@@ -459,26 +473,31 @@ class SREngine:
         that fused dispatch deferred: Algorithm-1 threshold trim from the
         (possibly one-frame-old) counts, straggler demotion on a missed
         deadline, and capacity growth after spill."""
-        img, ids, scores, counts, spills, health = rec["outs"]
-        img.block_until_ready()
-        done = time.perf_counter()
-        # marginal frame time: under async streaming a frame's launch-to-
-        # ready wall clock includes the device time of EARLIER in-flight
-        # frames — clocking from whichever is later (this frame's launch or
-        # the previous frame's completion) reports the pipelined per-frame
-        # service time, so fps aggregates are meaningful and a per-frame
-        # deadline does not fire spuriously on every steady-state frame.
-        # Synchronous calls are unaffected (the previous finalize always
-        # precedes the next launch).
-        dt = done - max(rec["t0"], self._fused_last_done)
-        self._fused_last_done = done
-        p, geom, streaming = rec["plan"], rec["geom"], rec["streaming"]
-        # materialize the in-graph health verdict (counts sync here anyway)
-        # and apply the host-visible side of the on_poison policy
-        health_t = None
-        if p.on_poison != "off":
-            health_t = tuple(int(c) for c in np.asarray(health))
-            if any(health_t):
+        with frame_span("essr.finalize", rec["index"]):
+            img, ids, scores, counts, spills, health = rec["outs"]
+            with span("essr.wait.image"):
+                img.block_until_ready()
+            done = time.perf_counter()
+            # marginal frame time: under async streaming a frame's launch-
+            # to-ready wall clock includes the device time of EARLIER
+            # in-flight frames — clocking from whichever is later (this
+            # frame's launch or the previous frame's completion) reports the
+            # pipelined per-frame service time, so fps aggregates are
+            # meaningful and a per-frame deadline does not fire spuriously
+            # on every steady-state frame. Synchronous calls are unaffected
+            # (the previous finalize always precedes the next launch).
+            dt = done - max(rec["t0"], self._fused_last_done)
+            self._fused_last_done = done
+            p, geom, streaming = rec["plan"], rec["geom"], rec["streaming"]
+            # materialize the in-graph health verdict with the counts (one
+            # host wait) and apply the host-visible side of the on_poison
+            # policy
+            with span("essr.wait.counts"):
+                health_t = (None if p.on_poison == "off" else
+                            tuple(int(c) for c in np.asarray(health)))
+                counts_t = tuple(int(c) for c in np.asarray(counts))
+                spills_t = tuple(int(s) for s in np.asarray(spills))
+            if health_t is not None and any(health_t):
                 self.guard.record(rec["index"], "poison",
                                   f"frame health nan/inf/oob={health_t} "
                                   f"(policy {p.on_poison})")
@@ -487,48 +506,49 @@ class SREngine:
                         f"frame failed health verdict "
                         f"nan/inf/oob={health_t} (plan.on_poison='raise')",
                         health=health_t)
-        steps = rec["steps"]
-        if streaming and p.watchdog_s is not None and dt > p.watchdog_s:
-            steps = steps + self.guard.note_watchdog(rec["index"], dt,
-                                                     p.watchdog_s)
-        counts_t = tuple(int(c) for c in np.asarray(counts))
-        spills_t = tuple(int(s) for s in np.asarray(spills))
-        macs = (self._macs if p.patch == self.plan.patch
-                else sp.SubnetMacs.make(self.cfg, p.patch))
-        saving = macs.saving_vs_c54(counts_t)
-        self._grow_caps(geom, p, counts_t, spills_t)
-        live = rec["thresholds"]
-        missed = False
-        shard_counts = None
-        if streaming:
-            self.switcher.observe_frame(counts_t[sp.C54])
-            missed = bool(self.deadline_s and dt > self.deadline_s)
-            if missed:
-                self.switcher.demote_for_straggler(severity=1.0)
-            live = self.switcher.thresholds
-            if self.bank is not None:
-                # reporting only: fused routing is one in-graph decision, so
-                # per-shard threshold control is a host-dispatch feature —
-                # strip counts are still surfaced for observability
-                shard_counts = tuple(
-                    sp.subnet_counts(np.asarray(ids)[sl])
-                    for sl in geom.shard_slices(self.plan.shards))
-        # ids/scores stay device arrays: the control loop only needs the
-        # scalar counts/spills, so the per-patch telemetry transfers lazily
-        # — consumers that index it (np.asarray) pay the copy, the
-        # steady-state stream does not
-        out = FrameResult(image=img, mode="edge_select",
-                          backend=self._variant_label(p, rec["variant"]),
-                          ids=ids, scores=scores, counts=counts_t,
-                          mac_saving=saving, latency_s=dt, thresholds=live,
-                          deadline_missed=missed, shards=self.plan.shards,
-                          shard_counts=shard_counts, dispatch="fused",
-                          spill_counts=spills_t, compiled=rec["compiled"],
-                          health=health_t, degraded=steps)
-        if streaming:
-            self.stats.append(dataclasses.replace(out, image=None,
-                                                  ids=None, scores=None))
-        return out
+            steps = rec["steps"]
+            if streaming and p.watchdog_s is not None and dt > p.watchdog_s:
+                steps = steps + self.guard.note_watchdog(rec["index"], dt,
+                                                         p.watchdog_s)
+            macs = (self._macs if p.patch == self.plan.patch
+                    else sp.SubnetMacs.make(self.cfg, p.patch))
+            saving = macs.saving_vs_c54(counts_t)
+            self._grow_caps(geom, p, counts_t, spills_t)
+            live = rec["thresholds"]
+            missed = False
+            shard_counts = None
+            if streaming:
+                self.switcher.observe_frame(counts_t[sp.C54])
+                missed = bool(self.deadline_s and dt > self.deadline_s)
+                if missed:
+                    self.switcher.demote_for_straggler(severity=1.0)
+                live = self.switcher.thresholds
+                if self.bank is not None:
+                    # reporting only: fused routing is one in-graph decision,
+                    # so per-shard threshold control is a host-dispatch
+                    # feature — strip counts are still surfaced for
+                    # observability
+                    with span("essr.wait.counts"):
+                        ids_np = np.asarray(ids)
+                    shard_counts = tuple(
+                        sp.subnet_counts(ids_np[sl])
+                        for sl in geom.shard_slices(self.plan.shards))
+            # ids/scores stay device arrays: the control loop only needs the
+            # scalar counts/spills, so the per-patch telemetry transfers
+            # lazily — consumers that index it (np.asarray) pay the copy,
+            # the steady-state stream does not
+            out = FrameResult(
+                image=img, mode="edge_select",
+                backend=self._variant_label(p, rec["variant"]),
+                ids=ids, scores=scores, counts=counts_t, mac_saving=saving,
+                latency_s=dt, thresholds=live, deadline_missed=missed,
+                shards=self.plan.shards, shard_counts=shard_counts,
+                dispatch="fused", spill_counts=spills_t,
+                compiled=rec["compiled"], health=health_t, degraded=steps)
+            if streaming:
+                self.stats.append(dataclasses.replace(out, image=None,
+                                                      ids=None, scores=None))
+            return out
 
     def _upscale_fused(self, frame, p: ExecutionPlan) -> FrameResult:
         """upscale()'s fused path: launch + finalize back-to-back (single
@@ -717,90 +737,96 @@ class SREngine:
             return self._upscale_fused(frame, p)
         t0 = time.perf_counter()
         index = self._next_index()
-        frame = self._ingest_frame(frame, p, index)
-        # host dispatch syncs per frame anyway, so the verdict runs eagerly;
-        # under "bilinear" a poisoned threshold-routed frame is forced to the
-        # dense fallback lane below (forced-width modes serve the sanitized
-        # frame through the requested subnet — the caller pinned the route)
-        frame, health_t, force_bilinear = self._host_health(frame, p, index)
+        with frame_span("essr.serve", index):
+            frame = self._ingest_frame(frame, p, index)
+            # host dispatch syncs per frame anyway, so the verdict runs
+            # eagerly; under "bilinear" a poisoned threshold-routed frame is
+            # forced to the dense fallback lane below (forced-width modes
+            # serve the sanitized frame through the requested subnet — the
+            # caller pinned the route)
+            frame, health_t, force_bilinear = self._host_health(frame, p,
+                                                                index)
 
-        widths = self.cfg.subnet_widths()
-        if mode == "whole":
-            if width is not None and width not in widths:
-                raise ValueError(f"mode='whole' needs width in {widths} "
-                                 f"(or None for full), got {width}")
-            compiled = self._mark_warm(
-                ("whole", (int(frame.shape[0]), int(frame.shape[1])), width))
-            img = sr_whole(self.params, frame, self.cfg, width=width)
-            img.block_until_ready()
-            # sr_whole always runs the pure-JAX path; label it honestly
-            return FrameResult(image=img, mode=mode, backend="ref",
-                               latency_s=time.perf_counter() - t0,
-                               compiled=compiled, health=health_t)
+            widths = self.cfg.subnet_widths()
+            if mode == "whole":
+                if width is not None and width not in widths:
+                    raise ValueError(f"mode='whole' needs width in {widths} "
+                                     f"(or None for full), got {width}")
+                compiled = self._mark_warm(
+                    ("whole", (int(frame.shape[0]), int(frame.shape[1])),
+                     width))
+                img = sr_whole(self.params, frame, self.cfg, width=width)
+                with span("essr.wait.image"):
+                    img.block_until_ready()
+                # sr_whole always runs the pure-JAX path; label it honestly
+                return FrameResult(image=img, mode=mode, backend="ref",
+                                   latency_s=time.perf_counter() - t0,
+                                   compiled=compiled, health=health_t)
 
-        # cached gather/scatter maps for this frame shape (zero host setup
-        # after the first frame of a given geometry)
-        geom = p.geometry(frame.shape[0], frame.shape[1], self.cfg.scale)
-        # first frame of a geometry pays trace+compile (an approximation for
-        # host dispatch, where unseen bucket sizes can still recompile later;
-        # exact for the fused path, which keys on its capacity profile)
-        compiled = self._mark_warm(("host", geom.cache_key))
-        scored = False
-        routed_by_thresholds = False
-        result_mode = mode
-        if mode == "all_patches":
-            if width not in widths:
-                raise ValueError(f"mode='all_patches' needs width in {widths}, "
-                                 f"got {width}")
-            res = sr_all_patches_result(self.params, frame, self.cfg, width,
-                                        patch=p.patch, overlap=p.overlap,
-                                        buckets=p.buckets, backend=self.backend,
-                                        interpret=p.interpret, geometry=geom,
-                                        mesh=self.mesh, quant=self.qpack,
-                                        fusion=p.fusion)
-        elif ids_override is None and p.subnet_policy != "threshold":
-            # forced policies ignore edge scores — reuse the no-scoring path;
-            # plan.decide is the single policy-name -> subnet-id mapping.
-            # Label what actually ran, so consumers keying on mode don't
-            # expect edge scores from a forced run.
-            result_mode = "all_patches"
-            forced = widths[int(p.decide(np.zeros(1))[0])]
-            res = sr_all_patches_result(self.params, frame, self.cfg, forced,
-                                        patch=p.patch, overlap=p.overlap,
-                                        buckets=p.buckets, backend=self.backend,
-                                        interpret=p.interpret, geometry=geom,
-                                        mesh=self.mesh, quant=self.qpack,
-                                        fusion=p.fusion)
-        else:
-            if force_bilinear and ids_override is None:
-                # poisoned frame under on_poison="bilinear": the dense
-                # fallback lane serves every patch (sanitized above)
-                ids_override = np.zeros(geom.n, np.int64)
-            # an explicit ids_override skips the edge unit entirely, so there
-            # are no scores to report for that path
-            scored = ids_override is None
-            routed_by_thresholds = ids_override is None
-            res = edge_selective_sr(self.params, frame, self.cfg,
-                                    t1=p.t1, t2=p.t2,
-                                    patch=p.patch, overlap=p.overlap,
-                                    ids_override=ids_override,
-                                    buckets=p.buckets, backend=self.backend,
-                                    interpret=p.interpret, geometry=geom,
-                                    mesh=self.mesh, quant=self.qpack,
-                                    fusion=p.fusion)
-        res.image.block_until_ready()
-        return FrameResult(image=res.image, mode=result_mode,
-                           backend=self._backend_label(p), ids=res.ids,
-                           scores=res.scores if scored else None,
-                           counts=res.counts, mac_saving=res.mac_saving,
-                           latency_s=time.perf_counter() - t0,
-                           # thresholds only meaningful when routing used them
-                           thresholds=(p.thresholds if routed_by_thresholds
-                                       else (0.0, 0.0)),
-                           # sharding is engine-level (like backend): a
-                           # per-call plan cannot rebuild the mesh
-                           shards=self.plan.shards, compiled=compiled,
-                           health=health_t)
+            # cached gather/scatter maps for this frame shape (zero host
+            # setup after the first frame of a given geometry)
+            geom = p.geometry(frame.shape[0], frame.shape[1], self.cfg.scale)
+            # first frame of a geometry pays trace+compile (an approximation
+            # for host dispatch, where unseen bucket sizes can still
+            # recompile later; exact for the fused path, which keys on its
+            # capacity profile)
+            compiled = self._mark_warm(("host", geom.cache_key))
+            scored = False
+            routed_by_thresholds = False
+            result_mode = mode
+            if mode == "all_patches":
+                if width not in widths:
+                    raise ValueError(f"mode='all_patches' needs width in "
+                                     f"{widths}, got {width}")
+                res = sr_all_patches_result(
+                    self.params, frame, self.cfg, width, patch=p.patch,
+                    overlap=p.overlap, buckets=p.buckets,
+                    backend=self.backend, interpret=p.interpret,
+                    geometry=geom, mesh=self.mesh, quant=self.qpack,
+                    fusion=p.fusion)
+            elif ids_override is None and p.subnet_policy != "threshold":
+                # forced policies ignore edge scores — reuse the no-scoring
+                # path; plan.decide is the single policy-name -> subnet-id
+                # mapping. Label what actually ran, so consumers keying on
+                # mode don't expect edge scores from a forced run.
+                result_mode = "all_patches"
+                forced = widths[int(p.decide(np.zeros(1))[0])]
+                res = sr_all_patches_result(
+                    self.params, frame, self.cfg, forced, patch=p.patch,
+                    overlap=p.overlap, buckets=p.buckets,
+                    backend=self.backend, interpret=p.interpret,
+                    geometry=geom, mesh=self.mesh, quant=self.qpack,
+                    fusion=p.fusion)
+            else:
+                if force_bilinear and ids_override is None:
+                    # poisoned frame under on_poison="bilinear": the dense
+                    # fallback lane serves every patch (sanitized above)
+                    ids_override = np.zeros(geom.n, np.int64)
+                # an explicit ids_override skips the edge unit entirely, so
+                # there are no scores to report for that path
+                scored = ids_override is None
+                routed_by_thresholds = ids_override is None
+                res = edge_selective_sr(
+                    self.params, frame, self.cfg, t1=p.t1, t2=p.t2,
+                    patch=p.patch, overlap=p.overlap,
+                    ids_override=ids_override, buckets=p.buckets,
+                    backend=self.backend, interpret=p.interpret,
+                    geometry=geom, mesh=self.mesh, quant=self.qpack,
+                    fusion=p.fusion)
+            with span("essr.wait.image"):
+                res.image.block_until_ready()
+            return FrameResult(
+                image=res.image, mode=result_mode,
+                backend=self._backend_label(p), ids=res.ids,
+                scores=res.scores if scored else None, counts=res.counts,
+                mac_saving=res.mac_saving,
+                latency_s=time.perf_counter() - t0,
+                # thresholds only meaningful when routing used them
+                thresholds=(p.thresholds if routed_by_thresholds
+                            else (0.0, 0.0)),
+                # sharding is engine-level (like backend): a per-call plan
+                # cannot rebuild the mesh
+                shards=self.plan.shards, compiled=compiled, health=health_t)
 
     def reference(self, frame: jax.Array, width: Optional[int] = None) -> FrameResult:
         """Whole-image convolution — the lossless reference of Table III."""
@@ -839,63 +865,72 @@ class SREngine:
                 frame, self.plan, self.switcher.thresholds, streaming=True))
         t0 = time.perf_counter()
         index = self._next_index()
-        frame = self._ingest_frame(frame, self.plan, index)
-        frame, health_t, force_bilinear = self._host_health(frame, self.plan,
-                                                            index)
-        geom = self.plan.geometry(frame.shape[0], frame.shape[1],
-                                  self.cfg.scale)
-        compiled = self._mark_warm(("host", geom.cache_key))
-        patches, pos = geom.extract(frame), geom.pos
-        scores = np.asarray(edge_score(patches))
-        sharded = self.bank is not None
-        slices = (geom.shard_slices(self.plan.shards) if sharded else None)
-        if force_bilinear:
-            # poisoned frame under on_poison="bilinear": serve the dense
-            # fallback lane; the switcher still observes (zero C54 load)
-            ids = np.zeros(len(scores), np.int64)
-        elif sharded:
-            ids = self.bank.assign(scores, slices)
-        else:
-            ids = self.switcher.assign(scores)
-        res = edge_selective_sr(self.params, frame, self.cfg,
-                                patch=self.plan.patch, overlap=self.plan.overlap,
-                                ids_override=ids, buckets=self.plan.buckets,
-                                backend=self.backend,
-                                interpret=self.plan.interpret, geometry=geom,
-                                mesh=self.mesh, quant=self.qpack,
-                                fusion=self.plan.fusion,
-                                precomputed=(patches, pos, scores))
-        res.image.block_until_ready()
-        dt = time.perf_counter() - t0
-        missed = bool(self.deadline_s and dt > self.deadline_s)
-        shard_counts = shard_thresholds = shard_missed = None
-        if sharded:
-            shard_counts = tuple(sp.subnet_counts(ids[sl]) for sl in slices)
-            shard_missed = self.bank.note_frame(
-                missed, [self._macs.total(c) for c in shard_counts])
-            shard_thresholds = self.bank.thresholds
-            # scalar thresholds field: across-shard mean (the per-shard truth
-            # is in shard_thresholds)
-            live = tuple(float(np.mean([t[i] for t in shard_thresholds]))
-                         for i in (0, 1))
-        else:
-            if missed:
-                self.switcher.demote_for_straggler(severity=1.0)
-            live = self.switcher.thresholds
-        out = FrameResult(image=res.image, mode="edge_select",
-                          backend=self.backend_label, ids=ids, scores=scores,
-                          counts=res.counts, mac_saving=res.mac_saving,
-                          latency_s=dt, thresholds=live,
-                          deadline_missed=missed, shards=self.plan.shards,
-                          shard_counts=shard_counts,
-                          shard_thresholds=shard_thresholds,
-                          shard_deadline_missed=shard_missed,
-                          compiled=compiled, health=health_t)
-        # retain only the compact record: holding every SR image would grow
-        # unboundedly over a long stream (one 8K frame is ~100s of MB)
-        self.stats.append(dataclasses.replace(out, image=None,
-                                              ids=None, scores=None))
-        return out
+        with frame_span("essr.serve", index):
+            frame = self._ingest_frame(frame, self.plan, index)
+            frame, health_t, force_bilinear = self._host_health(
+                frame, self.plan, index)
+            geom = self.plan.geometry(frame.shape[0], frame.shape[1],
+                                      self.cfg.scale)
+            compiled = self._mark_warm(("host", geom.cache_key))
+            with span("essr.extract"):
+                patches, pos = geom.extract(frame), geom.pos
+            sharded = self.bank is not None
+            slices = (geom.shard_slices(self.plan.shards) if sharded
+                      else None)
+            with span("essr.route"):
+                scores = edge_score(patches)
+                with span("essr.wait.scores"):
+                    scores = np.asarray(scores)
+                if force_bilinear:
+                    # poisoned frame under on_poison="bilinear": serve the
+                    # dense fallback lane; the switcher still observes (zero
+                    # C54 load)
+                    ids = np.zeros(len(scores), np.int64)
+                elif sharded:
+                    ids = self.bank.assign(scores, slices)
+                else:
+                    ids = self.switcher.assign(scores)
+            res = edge_selective_sr(
+                self.params, frame, self.cfg, patch=self.plan.patch,
+                overlap=self.plan.overlap, ids_override=ids,
+                buckets=self.plan.buckets, backend=self.backend,
+                interpret=self.plan.interpret, geometry=geom,
+                mesh=self.mesh, quant=self.qpack, fusion=self.plan.fusion,
+                precomputed=(patches, pos, scores))
+            with span("essr.wait.image"):
+                res.image.block_until_ready()
+            dt = time.perf_counter() - t0
+            missed = bool(self.deadline_s and dt > self.deadline_s)
+            shard_counts = shard_thresholds = shard_missed = None
+            if sharded:
+                shard_counts = tuple(sp.subnet_counts(ids[sl])
+                                     for sl in slices)
+                shard_missed = self.bank.note_frame(
+                    missed, [self._macs.total(c) for c in shard_counts])
+                shard_thresholds = self.bank.thresholds
+                # scalar thresholds field: across-shard mean (the per-shard
+                # truth is in shard_thresholds)
+                live = tuple(float(np.mean([t[i] for t in shard_thresholds]))
+                             for i in (0, 1))
+            else:
+                if missed:
+                    self.switcher.demote_for_straggler(severity=1.0)
+                live = self.switcher.thresholds
+            out = FrameResult(
+                image=res.image, mode="edge_select",
+                backend=self.backend_label, ids=ids, scores=scores,
+                counts=res.counts, mac_saving=res.mac_saving, latency_s=dt,
+                thresholds=live, deadline_missed=missed,
+                shards=self.plan.shards, shard_counts=shard_counts,
+                shard_thresholds=shard_thresholds,
+                shard_deadline_missed=shard_missed, compiled=compiled,
+                health=health_t)
+            # retain only the compact record: holding every SR image would
+            # grow unboundedly over a long stream (one 8K frame is ~100s of
+            # MB)
+            self.stats.append(dataclasses.replace(out, image=None,
+                                                  ids=None, scores=None))
+            return out
 
     def stream(self, frames: Iterable[jax.Array]) -> Iterator[FrameResult]:
         """Serve a frame stream; yields one FrameResult per frame.

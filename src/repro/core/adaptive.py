@@ -31,6 +31,7 @@ from typing import List, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.core import subnet_policy as sp
+from repro.core.phases import span
 
 
 @dataclasses.dataclass
@@ -67,7 +68,9 @@ class AdaptiveSwitcher:
         per-frame threshold adaptation.
         """
         scores = np.asarray(scores)
-        ids = np.array(sp.decide(scores, self.t1, self.t2))  # writable copy
+        ids = sp.decide(scores, self.t1, self.t2)
+        with span("essr.wait.route"):
+            ids = np.array(ids)                      # writable copy
 
         # --- hard ceiling over the current second -------------------------
         budget_left = self.cfg.c54_per_sec_budget - self._c54_this_second

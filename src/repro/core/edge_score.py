@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro.core.phases import phase_jit
 from repro.models.layers import rgb_to_luma
 
 # 4-neighbour Laplacian (the standard 3x3 form)
@@ -34,12 +35,13 @@ def laplacian_response(luma: jax.Array) -> jax.Array:
     return jnp.clip(jnp.abs(y), 0.0, 255.0)
 
 
-@jax.jit
+@phase_jit("essr_edge_score")
 def edge_score(patches: jax.Array) -> jax.Array:
     """(N,h,w,3) RGB in [0,1]  ->  (N,) edge scores in [0,255].
 
     jit'd: the serving path scores every patch batch of a stream, and the
-    shapes recur per geometry."""
+    shapes recur per geometry. Its executable is the ``essr_edge_score``
+    phase."""
     luma = rgb_to_luma(patches)
     resp = laplacian_response(luma)
     return resp.mean(axis=(1, 2))

@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.caching import bounded_cache
+from repro.core.phases import phase_jit
 
 
 def grid_starts(size: int, patch: int, overlap: int) -> np.ndarray:
@@ -75,11 +76,28 @@ def _reflect_pad_hw(img: jax.Array, pad_h: int, pad_w: int) -> jax.Array:
     h, w = int(img.shape[0]), int(img.shape[1])
     rh, rw = min(pad_h, max(h - 1, 0)), min(pad_w, max(w - 1, 0))
     if rh or rw:
-        img = jnp.pad(img, ((0, rh), (0, rw), (0, 0)), mode="reflect")
+        img = _extract_pad(img, ((0, rh), (0, rw), (0, 0)), "reflect")
     eh, ew = pad_h - rh, pad_w - rw
     if eh or ew:
-        img = jnp.pad(img, ((0, eh), (0, ew), (0, 0)), mode="edge")
+        img = _extract_pad(img, ((0, eh), (0, ew), (0, 0)), "edge")
     return img
+
+
+# The extract phase: pad, reshape, gather and reshape, each its own
+# executable.
+@phase_jit("essr_extract", static_argnames=("widths", "mode"))
+def _extract_pad(img, widths, mode):
+    return jnp.pad(img, widths, mode=mode)
+
+
+@phase_jit("essr_extract", static_argnames=("shape",))
+def _extract_reshape(x, shape):
+    return x.reshape(shape)
+
+
+@phase_jit("essr_extract")
+def _extract_gather(flat, idx):
+    return jnp.take(flat, idx, axis=0)
 
 
 @dataclasses.dataclass(frozen=True, eq=False)     # identity eq: fields hold arrays
@@ -142,9 +160,10 @@ class PatchGeometry:
         hp, wp = self.padded_hw
         if (hp, wp) != (h, w):
             img = _reflect_pad_hw(img, hp - h, wp - w)
-        flat = img.reshape(hp * wp, img.shape[-1])
+        flat = _extract_reshape(img, shape=(hp * wp, img.shape[-1]))
         p = self.patch
-        return jnp.take(flat, self.gather_idx, axis=0).reshape(self.n, p, p, -1)
+        return _extract_reshape(_extract_gather(flat, self.gather_idx),
+                                shape=(self.n, p, p, img.shape[-1]))
 
     def fuse_average(self, sr_patches: jax.Array) -> jax.Array:
         """(N, p*s, p*s, C) -> (H*s, W*s, C): separable scatter-add, then a
@@ -160,10 +179,17 @@ class PatchGeometry:
                               n_y=n_y, n_x=n_x, ps=self.patch * s,
                               hh=hp * s, wh=wp * s)
         h, w = self.hw
-        return out[:h * s, :w * s]
+        if (hp, wp) == (h, w):
+            return out
+        return _fuse_crop(out, hw=(h * s, w * s))
 
 
-@functools.partial(jax.jit, static_argnames=("n_y", "n_x", "ps", "hh", "wh"))
+@phase_jit("essr_fuse", static_argnames=("hw",))
+def _fuse_crop(out, hw):
+    return out[:hw[0], :hw[1]]
+
+
+@phase_jit("essr_fuse", static_argnames=("n_y", "n_x", "ps", "hh", "wh"))
 def _fuse_separable(sr, y_idx, x_idx, y_cnt, x_cnt, *, n_y: int, n_x: int,
                     ps: int, hh: int, wh: int):
     """Overlap-and-average over a cartesian patch grid as two axis folds.

@@ -42,6 +42,7 @@ from repro.core.caching import bounded_cache
 from repro.core.edge_score import edge_score
 from repro.core.patching import (PatchGeometry, extract_patches_loop,
                                  fuse_patches_average_loop, get_geometry)
+from repro.core.phases import lane_phase, phase_jit, span
 from repro.models.essr import ESSRConfig, essr_forward
 
 
@@ -55,7 +56,11 @@ def _bucket(n: int, buckets=DEFAULT_BUCKETS) -> int:
     return int(np.ceil(n / buckets[-1]) * buckets[-1])
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "width"))
+# inline=True on the per-subnet forwards (here and in repro.kernels): a
+# caller's jit, such as a lane's named executable or the fused frame, traces
+# them in place instead of nesting a call, which keeps its lowering as cheap
+# as calling them directly
+@functools.partial(jax.jit, static_argnames=("cfg", "width"), inline=True)
 def _forward_width_jit(params, patches, cfg: ESSRConfig, width: int):
     return essr_forward(params, patches, cfg, width=width)
 
@@ -118,7 +123,8 @@ def resolve_backend(name: str):
 # quantized per-subnet forwards (ExecutionPlan.quant = "fxp10" | "int8")
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.jit, static_argnames=("cfg", "width", "quant"))
+@functools.partial(jax.jit, static_argnames=("cfg", "width", "quant"),
+                   inline=True)
 def _forward_width_quant_ref_jit(params, patches, cfg: ESSRConfig, width: int,
                                  quant):
     from repro.quant.pams import quantized_essr_forward
@@ -210,8 +216,9 @@ def _sharded_forward_fn(backend: str, mesh, cfg: ESSRConfig, width: int,
     def local(params, patches):
         return forward(params, patches, cfg, width, interpret=interpret)
 
-    return jax.jit(jax.shard_map(local, mesh=mesh, in_specs=(P(), spec),
-                                 out_specs=spec, check_vma=False))
+    return phase_jit(lane_phase(width))(
+        jax.shard_map(local, mesh=mesh, in_specs=(P(), spec),
+                      out_specs=spec, check_vma=False))
 
 
 def sharded_forward(params, patches: jax.Array, cfg: ESSRConfig, width: int,
@@ -231,7 +238,48 @@ def sharded_forward(params, patches: jax.Array, cfg: ESSRConfig, width: int,
             [patches, jnp.repeat(patches[-1:], pad, axis=0)], axis=0)
     out = _sharded_forward_fn(backend, mesh, cfg, width, interpret, quant,
                               fusion)(params, patches)
-    return out[:n] if pad else out
+    return _lane_rows(lane_phase(width))(out, n=n) if pad else out
+
+
+@functools.lru_cache(maxsize=64)
+def _lane_forward(backend: str, quant, fusion: str, cfg: ESSRConfig,
+                  width: int, interpret: Optional[bool]):
+    """One subnet width's forward on one device as the executable of its
+    lane phase (`lane_phase`): the ``(params, patches)`` callable of the
+    host-dispatch path, cached per routing regime like
+    `_sharded_forward_fn`."""
+    forward = resolve_forward(backend, quant, fusion)
+
+    def lane(params, patches):
+        return forward(params, patches, cfg, width, interpret=interpret)
+
+    return phase_jit(lane_phase(width))(lane)
+
+
+@functools.lru_cache(maxsize=8)
+def _lane_rows(phase: str):
+    """``x[:n]`` as an executable of ``phase``: trims a lane's bucket
+    padding off its outputs."""
+    return phase_jit(phase, static_argnames=("n",))(lambda x, n: x[:n])
+
+
+# The lane dispatch phases of host dispatch: the bucket gather, and the
+# zeroed patch batch plus the scatter of each lane's outputs into it.
+@phase_jit("essr_lane_gather")
+def _lane_gather(patches, rows):
+    return jnp.take(patches, rows, axis=0)
+
+
+@phase_jit("essr_lane_scatter", static_argnames=("shape", "dtype"))
+def _lane_zeros(shape, dtype):
+    return jnp.zeros(shape, dtype)
+
+
+@phase_jit("essr_lane_scatter")
+def _lane_scatter(out, idx, sr):
+    # idx is np.flatnonzero output: strictly increasing, so the set-scatter
+    # is unique by construction and deterministic
+    return out.at[idx].set(sr, unique_indices=True, mode="drop")
 
 
 # ---------------------------------------------------------------------------
@@ -286,25 +334,20 @@ def _sanitize(frame: jax.Array) -> jax.Array:
                     0.0, 1.0)
 
 
-@functools.lru_cache(maxsize=1)
-def _health_jit():
-    return jax.jit(_health_counts)
-
-
-@functools.lru_cache(maxsize=1)
-def _sanitize_jit():
-    return jax.jit(_sanitize)
+# the frame guard's executables on the host-dispatch paths: one phase
+_health_jit = phase_jit("essr_health")(_health_counts)
+_sanitize_jit = phase_jit("essr_health")(_sanitize)
 
 
 def frame_health(frame: jax.Array) -> jax.Array:
     """Jitted health verdict for the host-dispatch paths (which already sync
     per frame; the fused paths compute the same counts in-graph instead)."""
-    return _health_jit()(frame)
+    return _health_jit(frame)
 
 
 def sanitize_frame(frame: jax.Array) -> jax.Array:
     """Jitted sanitize for the host-dispatch paths."""
-    return _sanitize_jit()(frame)
+    return _sanitize_jit(frame)
 
 
 def snap_capacity(n: int, buckets: Tuple[int, ...] = DEFAULT_BUCKETS,
@@ -442,7 +485,7 @@ def fused_frame_fn(geometry: PatchGeometry, caps: Tuple[int, ...],
                             for k in range(len(widths))])
         return geometry.fuse_average(out), eff, scores, counts, spills, health
 
-    return jax.jit(run)
+    return phase_jit("essr_fused_frame")(run)
 
 
 @bounded_cache(maxsize=128)
@@ -575,7 +618,7 @@ def fused_stream_frame_fn(geometry: PatchGeometry, streams: int,
              for k in range(1, len(widths))], axis=1)
         return images, eff, scores, counts, spills, health
 
-    return jax.jit(run)
+    return phase_jit("essr_fused_streams")(run)
 
 
 # essr: allow[ESSR201] — legacy surface kept for tests/benches; new modes go through SREngine
@@ -679,12 +722,15 @@ def edge_selective_sr(params: Dict[str, Any], frame: jax.Array, cfg: ESSRConfig,
     the "before" side of benchmarks/table11_throughput.py. Never the serving
     path.
     """
-    forward = resolve_forward(backend, quant, fusion)
     if mesh is not None and int(mesh.size) > 1:
-        def forward(params, patches, cfg, width, interpret=None):
+        def lane(params, patches, width):
             return sharded_forward(params, patches, cfg, width, mesh=mesh,
                                    backend=backend, interpret=interpret,
                                    quant=quant, fusion=fusion)
+    else:
+        def lane(params, patches, width):
+            return _lane_forward(backend, quant, fusion, cfg, width,
+                                 interpret)(params, patches)
     s = cfg.scale
     h, w = int(frame.shape[0]), int(frame.shape[1])
     g = geometry if geometry is not None else get_geometry(h, w, patch,
@@ -693,47 +739,58 @@ def edge_selective_sr(params: Dict[str, Any], frame: jax.Array, cfg: ESSRConfig,
         patches, pos, scores = precomputed
         scores = np.asarray(scores)
     else:
-        if use_loop_reference:
-            patches, pos = extract_patches_loop(frame, patch, overlap)
-        else:
-            patches, pos = g.extract(frame), g.pos
-        if ids_override is None:
-            scores = np.asarray(edge_score(patches))
-        else:
-            # forced routing never consults the edge unit (as on the ASIC);
-            # scores are reported as zeros rather than computed and discarded
-            scores = np.zeros(len(pos), np.float32)
-    ids = ids_override if ids_override is not None else np.asarray(sp.decide(scores, t1, t2))
+        with span("essr.extract"):
+            if use_loop_reference:
+                patches, pos = extract_patches_loop(frame, patch, overlap)
+            else:
+                patches, pos = g.extract(frame), g.pos
+        # forced routing never consults the edge unit (as on the ASIC);
+        # scores are reported as zeros rather than computed and discarded
+        scores = (None if ids_override is None
+                  else np.zeros(len(pos), np.float32))
+    if ids_override is not None:
+        ids = ids_override
+    else:
+        with span("essr.route"):
+            if scores is None:
+                scores = edge_score(patches)
+                with span("essr.wait.scores"):
+                    scores = np.asarray(scores)
+            ids = sp.decide(scores, t1, t2)
+            with span("essr.wait.route"):
+                ids = np.asarray(ids)
 
-    out_patches = jnp.zeros((patches.shape[0], patch * s, patch * s, 3),
-                            patches.dtype)
+    out_patches = _lane_zeros(
+        shape=(patches.shape[0], patch * s, patch * s, 3), dtype=patches.dtype)
     widths = cfg.subnet_widths()
     for k, width in enumerate(widths):
         idx = np.flatnonzero(ids == k)
         if idx.size == 0:
             continue
-        if idx.size == len(ids):
-            # one subnet took the whole frame: no gather/scatter, and no
-            # bucket padding (the full-batch shape recurs per geometry, so
-            # compilation stays bounded without it)
-            out_patches = forward(params, patches, cfg, width,
-                                  interpret=interpret)
-            continue
-        cap = _bucket(idx.size, buckets)
-        # pad with the bucket's own last index (not patch 0): the duplicate
-        # work is cache-friendly and never re-runs another subnet's patch
-        pad = np.concatenate([idx, np.full(cap - idx.size, idx[-1], idx.dtype)])
-        sr = forward(params, jnp.take(patches, jnp.asarray(pad), axis=0),
-                     cfg, width, interpret=interpret)[: idx.size]
-        # idx is np.flatnonzero output: strictly increasing, so the set-
-        # scatter is unique by construction and deterministic
-        out_patches = out_patches.at[jnp.asarray(idx)].set(
-            sr, unique_indices=True, mode="drop")
+        with span("essr.lane", width=width):
+            if idx.size == len(ids):
+                # one subnet took the whole frame: no gather/scatter, and no
+                # bucket padding (the full-batch shape recurs per geometry,
+                # so compilation stays bounded without it)
+                out_patches = lane(params, patches, width)
+                continue
+            cap = _bucket(idx.size, buckets)
+            # pad with the bucket's own last index (not patch 0): the
+            # duplicate work is cache-friendly and never re-runs another
+            # subnet's patch
+            pad = np.concatenate([idx, np.full(cap - idx.size, idx[-1],
+                                               idx.dtype)])
+            sr = lane(params, _lane_gather(patches, jnp.asarray(pad)), width)
+            if idx.size < cap:
+                sr = _lane_rows(lane_phase(width))(sr, n=idx.size)
+            out_patches = _lane_scatter(out_patches, jnp.asarray(idx), sr)
 
-    if use_loop_reference:
-        img = fuse_patches_average_loop(out_patches, pos, s, (h * s, w * s))
-    else:
-        img = g.fuse_average(out_patches)
+    with span("essr.fuse"):
+        if use_loop_reference:
+            img = fuse_patches_average_loop(out_patches, pos, s,
+                                            (h * s, w * s))
+        else:
+            img = g.fuse_average(out_patches)
     counts = sp.subnet_counts(ids)
     saving = sp.SubnetMacs.make(cfg, patch).saving_vs_c54(counts)
     return SRResult(image=img, ids=ids, scores=scores, counts=counts, mac_saving=saving)
@@ -757,7 +814,8 @@ def sr_all_patches_result(params, frame, cfg: ESSRConfig, width: int,
         raise ValueError(f"width {width} not one of the subnet widths {widths}")
     g = geometry if geometry is not None else get_geometry(
         int(frame.shape[0]), int(frame.shape[1]), patch, overlap, cfg.scale)
-    patches, pos = g.extract(frame), g.pos
+    with span("essr.extract"):
+        patches, pos = g.extract(frame), g.pos
     ids = np.full((len(pos),), widths.index(width), dtype=np.int64)
     return edge_selective_sr(params, frame, cfg, patch=patch, overlap=overlap,
                              ids_override=ids, buckets=buckets, backend=backend,

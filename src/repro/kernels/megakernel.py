@@ -306,7 +306,7 @@ def _mega_forward_jvp(cfg, width, block_patches, interpret,
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "width", "block_patches",
-                                             "interpret"))
+                                             "interpret"), inline=True)
 def essr_forward_megakernel(params, x, cfg: ESSRConfig,
                             width: Optional[int] = None,
                             block_patches: Optional[int] = None,
@@ -374,7 +374,8 @@ def _qmega_kernel(*refs, n_sfb: int, consts: Tuple[float, ...],
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "width", "pack",
-                                             "block_patches", "interpret"))
+                                             "block_patches", "interpret"),
+                   inline=True)
 def essr_forward_qmegakernel(params, x, cfg: ESSRConfig,
                              width: Optional[int] = None, *,
                              pack: QuantPack,

@@ -53,7 +53,8 @@ def default_block_patches(width: int, channels: int = 54, base: int = 4) -> int:
     return base * max(1, channels // max(width, 1))
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "width", "block_patches", "interpret"))
+@functools.partial(jax.jit, static_argnames=("cfg", "width", "block_patches", "interpret"),
+                   inline=True)
 def essr_forward_kernels(params, x, cfg: ESSRConfig, width: Optional[int] = None,
                          block_patches: Optional[int] = None,
                          interpret: Optional[bool] = None):

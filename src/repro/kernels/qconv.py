@@ -396,7 +396,8 @@ def _sfb_consts(consts: Dict[str, float], i: int) -> Tuple[float, ...]:
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.jit, static_argnames=("cfg", "width", "pack",
-                                             "block_patches", "interpret"))
+                                             "block_patches", "interpret"),
+                   inline=True)
 def essr_forward_qkernels(params, x, cfg: ESSRConfig,
                           width: Optional[int] = None, *,
                           pack: QuantPack, block_patches: Optional[int] = None,
