@@ -4,11 +4,14 @@ The paper's final choice: LR patches overlap by 2 px ("slim overlap block
 convolution"); after x4 upsampling the SR patches overlap by 8 px ("thick
 overlap"), and overlapped pixels are averaged ("overlap and average").
 
-Execution model: the hot path is device-resident. All per-patch index maps
-(one gather map for extraction, one scatter map + overlap counts for fusion)
-are computed ONCE per (H, W, patch, overlap, scale) geometry and LRU-cached
-(:func:`get_geometry`), so repeated frames of a stream pay zero host-side
-setup: extraction is a single device gather, fusion a single scatter-add.
+Execution model: the hot path is device-resident. Every tiling is a
+cartesian grid ``ys x xs``, so its index maps are per axis: the LR rows and
+columns each patch reads (extraction) and the HR rows and columns each patch
+writes, with per-axis overlap counts (fusion). They are computed ONCE per
+(H, W, patch, overlap, scale) geometry and LRU-cached (:func:`get_geometry`),
+so repeated frames of a stream pay zero host-side setup: extraction is one
+executable that takes whole rows, then whole columns, and folds them into
+patches; fusion is the mirror image, a row fold and a column fold.
 The seed's per-patch ``dynamic_slice`` / ``dynamic_update_slice`` loops are
 retained as ``*_loop`` reference oracles (equivalence-tested, and used by the
 before/after measurement in benchmarks/table11_throughput.py).
@@ -76,28 +79,32 @@ def _reflect_pad_hw(img: jax.Array, pad_h: int, pad_w: int) -> jax.Array:
     h, w = int(img.shape[0]), int(img.shape[1])
     rh, rw = min(pad_h, max(h - 1, 0)), min(pad_w, max(w - 1, 0))
     if rh or rw:
-        img = _extract_pad(img, ((0, rh), (0, rw), (0, 0)), "reflect")
+        img = jnp.pad(img, ((0, rh), (0, rw), (0, 0)), mode="reflect")
     eh, ew = pad_h - rh, pad_w - rw
     if eh or ew:
-        img = _extract_pad(img, ((0, eh), (0, ew), (0, 0)), "edge")
+        img = jnp.pad(img, ((0, eh), (0, ew), (0, 0)), mode="edge")
     return img
 
 
-# The extract phase: pad, reshape, gather and reshape, each its own
-# executable.
-@phase_jit("essr_extract", static_argnames=("widths", "mode"))
-def _extract_pad(img, widths, mode):
-    return jnp.pad(img, widths, mode=mode)
+@phase_jit("essr_extract", static_argnames=("patch",))
+def _extract_grid(img, y_rows, x_cols, *, patch: int):
+    """(H,W,C) -> (n_y*n_x, patch, patch, C), patches in raster order.
 
-
-@phase_jit("essr_extract", static_argnames=("shape",))
-def _extract_reshape(x, shape):
-    return x.reshape(shape)
-
-
-@phase_jit("essr_extract")
-def _extract_gather(flat, idx):
-    return jnp.take(flat, idx, axis=0)
+    The extract phase, one executable: pad a frame smaller than the patch,
+    take the grid's rows (``y_rows``, ``n_y*patch`` LR row indices), then
+    its columns (``x_cols``), and fold the ``(n_y*p, n_x*p)`` block plane
+    into patches. Every index is in bounds by construction, so the takes
+    clamp (a no-op) rather than build a fill mask."""
+    h, w = int(img.shape[0]), int(img.shape[1])
+    hp, wp = max(h, patch), max(w, patch)
+    if (hp, wp) != (h, w):
+        img = _reflect_pad_hw(img, hp - h, wp - w)
+    n_y, n_x = y_rows.shape[0] // patch, x_cols.shape[0] // patch
+    c = img.shape[-1]
+    t = jnp.take(img, y_rows, axis=0, mode="clip", indices_are_sorted=True)
+    t = jnp.take(t, x_cols, axis=1, mode="clip", indices_are_sorted=True)
+    t = t.reshape(n_y, patch, n_x, patch, c).transpose(0, 2, 1, 3, 4)
+    return t.reshape(n_y * n_x, patch, patch, c)
 
 
 @dataclasses.dataclass(frozen=True, eq=False)     # identity eq: fields hold arrays
@@ -109,11 +116,12 @@ class PatchGeometry:
     coordinates; ``padded_hw >= hw`` only when the frame is smaller than the
     patch, in which case :meth:`fuse_average` crops back to ``hw * scale``.
 
-    Fusion is *separable*: the grid is a cartesian product ``ys x xs``, so
-    overlap-add runs as one row-slice scatter along y and one column scatter
-    along x (``n_y*ps + n_x*ps`` fat slices instead of ``N*ps*ps`` scalar
-    rows — ~2.5x faster than a flat scatter on CPU, and the shape XLA tiles
-    well on TPU).
+    Both directions are *separable*: the grid is a cartesian product
+    ``ys x xs``, so extraction takes ``n_y*p`` whole LR rows, then ``n_x*p``
+    whole columns (``y_rows``/``x_cols``), and overlap-add runs as one
+    row-slice scatter along y and one column scatter along x (``n_y*ps +
+    n_x*ps`` fat slices instead of ``N*ps*ps`` scalar rows — the shape XLA
+    tiles well on TPU).
     """
     hw: Tuple[int, int]            # original LR frame size
     padded_hw: Tuple[int, int]     # reflect-padded (>= patch) LR size
@@ -122,7 +130,8 @@ class PatchGeometry:
     scale: int
     pos: np.ndarray                # (N, 2) LR-space (y, x) patch starts
     grid_yx: Tuple[int, int]       # (n_y, n_x): pos is their cartesian product
-    gather_idx: jax.Array          # (N*p*p,) linear indices into the LR plane
+    y_rows: jax.Array              # (n_y*p,) LR row index per patch row
+    x_cols: jax.Array              # (n_x*p,) LR col index per patch col
     y_idx: jax.Array               # (n_y*ps,) HR row index per patch row
     x_idx: jax.Array               # (n_x*ps,) HR col index per patch col
     # overlap multiplicity factors per axis (>= 1): the cartesian grid makes
@@ -151,19 +160,13 @@ class PatchGeometry:
         return shard_slices(self.n, shards)
 
     def extract(self, img: jax.Array) -> jax.Array:
-        """(H,W,C) -> (N,patch,patch,C): one device gather.
+        """(H,W,C) -> (N,patch,patch,C): one executable of whole-row and
+        whole-column takes (:func:`_extract_grid`).
 
         Traceable: safe to call on a traced ``img`` inside an enclosing jit
         (the fused frame graph does) — the index maps close over as
         constants and the reflect-pad path is shape-static."""
-        h, w = self.hw
-        hp, wp = self.padded_hw
-        if (hp, wp) != (h, w):
-            img = _reflect_pad_hw(img, hp - h, wp - w)
-        flat = _extract_reshape(img, shape=(hp * wp, img.shape[-1]))
-        p = self.patch
-        return _extract_reshape(_extract_gather(flat, self.gather_idx),
-                                shape=(self.n, p, p, img.shape[-1]))
+        return _extract_grid(img, self.y_rows, self.x_cols, patch=self.patch)
 
     def fuse_average(self, sr_patches: jax.Array) -> jax.Array:
         """(N, p*s, p*s, C) -> (H*s, W*s, C): separable scatter-add, then a
@@ -237,41 +240,41 @@ def get_geometry(h: int, w: int, patch: int = 32, overlap: int = 2,
     `SREngine` sizes it together with the compiled-executable caches via
     `core.pipeline.configure_compiled_caches`, and its occupancy rides
     `FrameResult.summary()`."""
-    pos, gather_idx, (hp, wp), (n_y, n_x) = _extract_maps(h, w, patch, overlap)
+    pos, y_rows, x_cols, (hp, wp), (n_y, n_x) = _extract_maps(
+        h, w, patch, overlap)
     ys, xs = np.unique(pos[:, 0]), np.unique(pos[:, 1])
     y_idx, x_idx, y_cnt, x_cnt = _cartesian_maps(
         ys.tobytes(), xs.tobytes(), patch, scale, hp, wp)
     return PatchGeometry(
         hw=(h, w), padded_hw=(hp, wp), patch=patch, overlap=overlap,
         scale=scale, pos=pos, grid_yx=(n_y, n_x),
-        gather_idx=gather_idx,
+        y_rows=y_rows, x_cols=x_cols,
         y_idx=y_idx, x_idx=x_idx, y_cnt=y_cnt, x_cnt=x_cnt)
 
 
 @functools.lru_cache(maxsize=128)
 def _extract_maps(h: int, w: int, patch: int, overlap: int):
-    """Scale-independent LR-side maps: positions + gather index + padded dims.
-    Shared by `get_geometry` (every scale) and standalone `extract_patches`,
-    so the gather map exists once per (h, w, patch, overlap)."""
+    """Scale-independent LR-side maps: positions, the LR rows and columns
+    each patch row/column reads, padded dims and grid shape. Shared by
+    `get_geometry` (every scale) and standalone `extract_patches`, so the
+    maps exist once per (h, w, patch, overlap)."""
     hp, wp = max(h, patch), max(w, patch)
     ys, xs = grid_starts(hp, patch, overlap), grid_starts(wp, patch, overlap)
     pos = np.array([(y, x) for y in ys for x in xs], dtype=np.int64)
     pos.setflags(write=False)   # cached + shared: a mutating caller would
-    return (pos, jnp.asarray(_index_maps(pos, patch, wp, 1), jnp.int32),
-            (hp, wp), (len(ys), len(xs)))   # corrupt every later frame
+    return (pos,                # corrupt every later frame
+            jnp.asarray(_axis_idx(ys, patch, 1), jnp.int32),
+            jnp.asarray(_axis_idx(xs, patch, 1), jnp.int32),
+            (hp, wp), (len(ys), len(xs)))
 
 
 def extract_patches(img: jax.Array, patch: int = 32, overlap: int = 2
                     ) -> Tuple[jax.Array, np.ndarray]:
-    """(H,W,C) -> ((N,patch,patch,C), positions (N,2)): one device gather
-    over the cached scale-independent LR maps."""
+    """(H,W,C) -> ((N,patch,patch,C), positions (N,2)): the same executable
+    as `PatchGeometry.extract`, over the cached scale-independent LR maps."""
     h, w = int(img.shape[0]), int(img.shape[1])
-    pos, gather_idx, (hp, wp), _ = _extract_maps(h, w, patch, overlap)
-    if (hp, wp) != (h, w):
-        img = _reflect_pad_hw(img, hp - h, wp - w)
-    flat = img.reshape(hp * wp, img.shape[-1])
-    return (jnp.take(flat, gather_idx, axis=0
-                     ).reshape(len(pos), patch, patch, -1), pos)
+    pos, y_rows, x_cols, _, _ = _extract_maps(h, w, patch, overlap)
+    return _extract_grid(img, y_rows, x_cols, patch=patch), pos
 
 
 def _axis_cnt(starts: np.ndarray, patch: int, scale: int,

@@ -1,4 +1,5 @@
 """Slim-overlap patching + overlap-average fusion (Sec. IV-I)."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -59,6 +60,54 @@ def test_vectorized_extract_matches_loop(h, w, patch, overlap, scale):
     pl, posl = extract_patches_loop(img, patch, overlap)
     assert np.array_equal(posv, posl)
     np.testing.assert_array_equal(np.asarray(pv), np.asarray(pl))
+
+
+@pytest.mark.parametrize("h,w,patch,overlap,scale", SWEEP)
+def test_geometry_extract_matches_loop(h, w, patch, overlap, scale):
+    """The serving path's `PatchGeometry.extract` is bit-exact against the
+    seed's per-patch slices, in the same raster order."""
+    img = jnp.asarray(np.random.default_rng(1).uniform(
+        0, 1, (h, w, 3)).astype(np.float32))
+    g = get_geometry(h, w, patch, overlap, scale)
+    pl, posl = extract_patches_loop(img, patch, overlap)
+    assert np.array_equal(g.pos, posl)
+    np.testing.assert_array_equal(np.asarray(g.extract(img)), np.asarray(pl))
+
+
+CELL_GEOMETRIES = [(1080, 1920, 4), (2160, 3840, 2)]   # the benchmark's cells
+
+
+@pytest.mark.parametrize("h,w,scale", CELL_GEOMETRIES)
+def test_cell_geometry_extract_matches_numpy_slices(h, w, scale):
+    """At the cells' frame sizes, a few patches (first, interior, the
+    clamped last row and column, the last) equal plain numpy slices."""
+    img = np.random.default_rng(4).uniform(0, 1, (h, w, 3)).astype(np.float32)
+    g = get_geometry(h, w, 32, 2, scale)
+    n_y, n_x = g.grid_yx
+    patches = np.asarray(g.extract(jnp.asarray(img)))
+    assert patches.shape == (n_y * n_x, 32, 32, 3)
+    assert g.pos[-1].tolist() == [h - 32, w - 32]   # clamped, not stride-30
+    picks = [0, 1, n_x - 1, n_x * (n_y // 2) + n_x // 2,
+             (n_y - 1) * n_x, (n_y - 1) * n_x + 5, 7 * n_x + n_x - 1,
+             n_y * n_x - 1]
+    for i in picks:
+        y, x = g.pos[i]
+        np.testing.assert_array_equal(patches[i], img[y:y + 32, x:x + 32])
+
+
+@pytest.mark.parametrize("h,w", [(64, 94), (20, 50)])     # no pad; pad
+def test_geometry_extract_under_vmap(h, w):
+    """`jax.vmap(geometry.extract)`, as the multi-stream fused graph calls
+    it, extracts each frame of the batch exactly."""
+    frames = jnp.asarray(np.random.default_rng(5).uniform(
+        0, 1, (3, h, w, 3)).astype(np.float32))
+    g = get_geometry(h, w, 32, 2, 2)
+    out = np.asarray(jax.jit(jax.vmap(g.extract))(frames))
+    assert out.shape == (3, g.n, 32, 32, 3)
+    for k in range(3):
+        np.testing.assert_array_equal(out[k], np.asarray(g.extract(frames[k])))
+        np.testing.assert_array_equal(
+            out[k], np.asarray(extract_patches(frames[k], 32, 2)[0]))
 
 
 @pytest.mark.parametrize("h,w,patch,overlap,scale", SWEEP)

@@ -17,6 +17,7 @@ from jax.profiler import ProfileData
 
 from repro.api import ExecutionPlan, SREngine
 from repro.core.adaptive import SwitchingConfig
+from repro.core.patching import get_geometry
 from repro.core.phases import lane_phase, phase_jit
 from repro.models.essr import ESSRConfig, init_essr
 
@@ -148,6 +149,34 @@ def test_profiling_changes_no_served_bit(dispatch, request):
     for (img, ids), (img0, ids0) in zip(traced, plain):
         np.testing.assert_array_equal(img, img0)
         np.testing.assert_array_equal(ids, ids0)
+
+
+@pytest.mark.parametrize("hw", [(60, 90), (20, 50)])    # no pad; pad
+def test_extract_is_one_executable(hw, tmp_path):
+    """One frame's extract launches exactly one executable, the phase
+    ``essr_extract``, whether or not the frame is padded to the patch."""
+    g = get_geometry(*hw, 32, 2, 2)
+    frame = jnp.asarray(np.random.default_rng(0).uniform(
+        0, 1, (*hw, 3)).astype(np.float32))
+    g.extract(frame).block_until_ready()        # warm: nothing compiles
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        g.extract(frame).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    calls = sorted((e.start_ns, e.start_ns + e.duration_ns, e.name)
+                   for plane in ProfileData.from_file(path).planes
+                   if plane.name.startswith("/host:CPU")
+                   for line in plane.lines for e in line.events
+                   if e.name.startswith("PjitFunction("))
+    # a call may record nested events of the same name: count the outermost
+    outer, end = [], -1.0
+    for start, stop, name in calls:
+        if start >= end:
+            outer.append(name)
+            end = stop
+    assert outer == ["PjitFunction(essr_extract)"], calls
 
 
 def test_phase_jit_names_the_module():
