@@ -47,6 +47,7 @@ from typing import Any, Deque, Dict, Iterable, Iterator, Optional, Tuple
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.api.plan import ExecutionPlan
 from repro.api.result import FrameResult, summarize_stats
@@ -134,12 +135,19 @@ class SREngine:
         # to the single-device path with identical numerics.
         self.bank: Optional[ShardSwitcherBank] = None
         self.mesh = None
+        self.lane_params = params
         if self.plan.shards > 1:
             self.bank = ShardSwitcherBank(base_switching,
                                           shards=self.plan.shards)
             avail = jax.device_count()
             if avail > 1:
                 self.mesh = make_patch_mesh(min(self.plan.shards, avail))
+                # every chip holds the whole network for the host-dispatch
+                # lanes, placed once here: a lane given the weights on one
+                # device would copy all of them to the other chips again at
+                # every call. Every other path keeps ``params`` as given.
+                self.lane_params = jax.device_put(
+                    params, NamedSharding(self.mesh, PartitionSpec()))
                 if avail < self.plan.shards:
                     warnings.warn(
                         f"plan.shards={self.plan.shards} but only {avail} "
@@ -779,7 +787,7 @@ class SREngine:
                     raise ValueError(f"mode='all_patches' needs width in "
                                      f"{widths}, got {width}")
                 res = sr_all_patches_result(
-                    self.params, frame, self.cfg, width, patch=p.patch,
+                    self.lane_params, frame, self.cfg, width, patch=p.patch,
                     overlap=p.overlap, buckets=p.buckets,
                     backend=self.backend, interpret=p.interpret,
                     geometry=geom, mesh=self.mesh, quant=self.qpack,
@@ -792,7 +800,7 @@ class SREngine:
                 result_mode = "all_patches"
                 forced = widths[int(p.decide(np.zeros(1))[0])]
                 res = sr_all_patches_result(
-                    self.params, frame, self.cfg, forced, patch=p.patch,
+                    self.lane_params, frame, self.cfg, forced, patch=p.patch,
                     overlap=p.overlap, buckets=p.buckets,
                     backend=self.backend, interpret=p.interpret,
                     geometry=geom, mesh=self.mesh, quant=self.qpack,
@@ -807,7 +815,7 @@ class SREngine:
                 scored = ids_override is None
                 routed_by_thresholds = ids_override is None
                 res = edge_selective_sr(
-                    self.params, frame, self.cfg, t1=p.t1, t2=p.t2,
+                    self.lane_params, frame, self.cfg, t1=p.t1, t2=p.t2,
                     patch=p.patch, overlap=p.overlap,
                     ids_override=ids_override, buckets=p.buckets,
                     backend=self.backend, interpret=p.interpret,
@@ -891,7 +899,7 @@ class SREngine:
                 else:
                     ids = self.switcher.assign(scores)
             res = edge_selective_sr(
-                self.params, frame, self.cfg, patch=self.plan.patch,
+                self.lane_params, frame, self.cfg, patch=self.plan.patch,
                 overlap=self.plan.overlap, ids_override=ids,
                 buckets=self.plan.buckets, backend=self.backend,
                 interpret=self.plan.interpret, geometry=geom,

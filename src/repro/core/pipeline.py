@@ -241,6 +241,106 @@ def sharded_forward(params, patches: jax.Array, cfg: ESSRConfig, width: int,
     return _lane_rows(lane_phase(width))(out, n=n) if pad else out
 
 
+# The cross-chip movement of a host-dispatch lane over the patch mesh. The
+# frame's chip (the root) keeps the extract, the routing and the fuse; a
+# lane's batch crosses to the shards in `essr_shard_scatter` and its HR
+# outputs come back in `essr_shard_gather`, each one executable over the
+# mesh whose transfers are collective permutes from and to the root. A
+# shard_map takes and gives one equal block per chip, so the scatter's
+# input stacks the root's batch with a filler block on every other chip,
+# and the gather's output block is the whole batch on the root and zeros,
+# left unread, on the other chips.
+
+@bounded_cache(maxsize=16)             # resized and reported with the
+                                       # compiled caches
+def _shard_filler(mesh, root: int, shape: Tuple[int, ...], dtype):
+    """One zero block of ``shape`` on every chip of ``mesh`` but the root,
+    made once per lane shape and kept."""
+    return {i: jax.device_put(np.zeros(shape, dtype), d)
+            for i, d in enumerate(mesh.devices.flat) if i != root}
+
+
+@functools.lru_cache(maxsize=8)
+def _shard_scatter_fn(mesh, root: int):
+    """``essr_shard_scatter``: the root's ``(n, ...)`` block of the stacked
+    input -> ``ceil(n / k)`` patches a chip, sharded over the mesh in
+    raster order (the last patch repeated into the padding)."""
+    from repro.distributed.sharding import patch_batch_spec
+    spec, k = patch_batch_spec(mesh), int(mesh.size)
+    axis = spec[0]
+
+    def local(x):
+        n = x.shape[0]
+        m = -(-n // k)
+        if k * m > n:
+            x = jnp.concatenate([x, jnp.repeat(x[-1:], k * m - n, axis=0)])
+        i = jax.lax.axis_index(axis)
+        out = x[root * m:(root + 1) * m]
+        for d in range(k):
+            if d != root:
+                got = jax.lax.ppermute(x[d * m:(d + 1) * m], axis,
+                                       perm=[(root, d)])
+                out = jnp.where(i == d, got, out)
+        return out
+
+    return phase_jit("essr_shard_scatter")(
+        jax.shard_map(local, mesh=mesh, in_specs=spec, out_specs=spec,
+                      check_vma=False))
+
+
+@functools.lru_cache(maxsize=16)
+def _shard_gather_fn(mesh, root: int, n: int):
+    """``essr_shard_gather``: each chip's block of lane outputs -> the first
+    ``n`` rows of all of them, in shard order, on the root (zeros on the
+    other chips, which only send)."""
+    from repro.distributed.sharding import patch_batch_spec
+    spec, k = patch_batch_spec(mesh), int(mesh.size)
+    axis = spec[0]
+
+    def local(y):
+        parts = [y if d == root else
+                 jax.lax.ppermute(y, axis, perm=[(d, root)])
+                 for d in range(k)]
+        return jax.lax.cond(
+            jax.lax.axis_index(axis) == root,
+            lambda: jnp.concatenate(parts, axis=0)[:n],
+            lambda: jnp.zeros((n, *y.shape[1:]), y.dtype))
+
+    return phase_jit("essr_shard_gather")(
+        jax.shard_map(local, mesh=mesh, in_specs=spec, out_specs=spec,
+                      check_vma=False))
+
+
+def sharded_lane(params, patches: jax.Array, cfg: ESSRConfig, width: int, *,
+                 mesh, backend: str, interpret: Optional[bool], quant,
+                 fusion: str) -> jax.Array:
+    """One host-dispatch lane over ``mesh``: scatter the batch from its chip
+    to the shards, run the subnet on every shard (`_sharded_forward_fn`),
+    gather the outputs back. Returns ``(n, p*s, p*s, C)`` on the chip that
+    held ``patches``, which is that of the frame."""
+    from repro.distributed.sharding import patch_batch_sharding
+    devices = list(mesh.devices.flat)
+    (dev,) = patches.devices()
+    if dev not in devices:
+        dev = devices[0]
+        patches = jax.device_put(patches, dev)
+    root, n = devices.index(dev), int(patches.shape[0])
+    with span("essr.shard"):
+        filler = _shard_filler(mesh, root, tuple(patches.shape),
+                               patches.dtype)
+        stacked = jax.make_array_from_single_device_arrays(
+            (len(devices) * n, *patches.shape[1:]),
+            patch_batch_sharding(mesh),
+            [patches if i == root else filler[i]
+             for i in range(len(devices))])
+        shards = _shard_scatter_fn(mesh, root)(stacked)
+    out = _sharded_forward_fn(backend, mesh, cfg, width, interpret, quant,
+                              fusion)(params, shards)
+    with span("essr.shard"):
+        out = _shard_gather_fn(mesh, root, n)(out)
+        return next(s.data for s in out.addressable_shards if s.device == dev)
+
+
 @functools.lru_cache(maxsize=64)
 def _lane_forward(backend: str, quant, fusion: str, cfg: ESSRConfig,
                   width: int, interpret: Optional[bool]):
@@ -646,11 +746,14 @@ def fused_frame_forward(params, frame, cfg: ESSRConfig, *,
 #: objects. Keyed by what each cache memoizes; the geometry cache lives in
 #: core.patching but is sized and surfaced together with the executables
 #: (an evicted geometry would re-key — and silently re-trace — the frame
-#: executables built on its identity).
+#: executables built on its identity). The sharded lane's zero filler
+#: blocks (`_shard_filler`, device memory per lane shape) are bounded and
+#: surfaced with them.
 COMPILED_CACHES = {
     "fused_frame_fn": fused_frame_fn,
     "fused_stream_frame_fn": fused_stream_frame_fn,
     "get_geometry": get_geometry,
+    "shard_filler": _shard_filler,
 }
 
 
@@ -704,10 +807,11 @@ def edge_selective_sr(params: Dict[str, Any], frame: jax.Array, cfg: ESSRConfig,
     host work is index-free.
 
     ``mesh``: optional 1-D device mesh (``launch.mesh.make_patch_mesh``).
-    When given with size > 1, every per-subnet batch is split across its
-    devices (shard_map data parallel, params replicated) and fused back
-    through the same scatter-add geometry — numerically identical to the
-    single-device path. ``None`` or size 1 is exactly the old path.
+    When given with size > 1, every per-subnet batch crosses from the
+    frame's chip to the mesh, is split across its devices (shard_map data
+    parallel, params replicated) and comes back to the frame's chip for the
+    fuse (`sharded_lane`) — numerically identical to the single-device
+    path. ``None`` or size 1 is exactly the old path.
 
     ``precomputed``: optional (patches, pos, scores) from a caller that
     already extracted/scored this frame (the streaming path scores patches
@@ -724,9 +828,9 @@ def edge_selective_sr(params: Dict[str, Any], frame: jax.Array, cfg: ESSRConfig,
     """
     if mesh is not None and int(mesh.size) > 1:
         def lane(params, patches, width):
-            return sharded_forward(params, patches, cfg, width, mesh=mesh,
-                                   backend=backend, interpret=interpret,
-                                   quant=quant, fusion=fusion)
+            return sharded_lane(params, patches, cfg, width, mesh=mesh,
+                                backend=backend, interpret=interpret,
+                                quant=quant, fusion=fusion)
     else:
         def lane(params, patches, width):
             return _lane_forward(backend, quant, fusion, cfg, width,
