@@ -42,7 +42,7 @@ SUBNET_POLICIES = ("threshold", "all_bilinear", "all_c27", "all_c54")
 #:               over subnet buckets (supports every mode/policy/override)
 #: ``"fused"`` — one compiled frame executable per (geometry, capacity
 #:               profile): extract -> edge-score -> threshold routing ->
-#:               capacity-slotted per-subnet forward -> scatter-add fusion,
+#:               capacity-slotted per-subnet forward -> overlap-average fusion,
 #:               no host in the loop (threshold-routed edge_select only;
 #:               other modes fall back to host dispatch, documented in
 #:               docs/api.md "Dispatch modes & async streaming").

@@ -5,13 +5,16 @@ convolution"); after x4 upsampling the SR patches overlap by 8 px ("thick
 overlap"), and overlapped pixels are averaged ("overlap and average").
 
 Execution model: the hot path is device-resident. Every tiling is a
-cartesian grid ``ys x xs``, so its index maps are per axis: the LR rows and
-columns each patch reads (extraction) and the HR rows and columns each patch
-writes, with per-axis overlap counts (fusion). They are computed ONCE per
-(H, W, patch, overlap, scale) geometry and LRU-cached (:func:`get_geometry`),
-so repeated frames of a stream pay zero host-side setup: extraction is one
-executable that takes whole rows, then whole columns, and folds them into
-patches; fusion is the mirror image, a row fold and a column fold.
+cartesian grid ``ys x xs``, so its maps are per axis: the LR rows and
+columns each patch reads (extraction) and each axis's fold (fusion). They
+are computed ONCE per (H, W, patch, overlap, scale) geometry and LRU-cached
+(:func:`get_geometry`), so repeated frames of a stream pay zero host-side
+setup: extraction is one executable that takes whole rows, then whole
+columns, and folds them into patches; fusion is one executable that lays
+the patches side by side and folds them along y, then x, by static slices
+(the slice fold), or, on a grid where a pixel can lie in three patches
+along an axis or a frame under two patches a side, by one flat
+scatter-add.
 The seed's per-patch ``dynamic_slice`` / ``dynamic_update_slice`` loops are
 retained as ``*_loop`` reference oracles (equivalence-tested, and used by the
 before/after measurement in benchmarks/table11_throughput.py).
@@ -29,7 +32,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import numpy as np
 import jax
@@ -107,21 +110,78 @@ def _extract_grid(img, y_rows, x_cols, *, patch: int):
     return t.reshape(n_y * n_x, patch, patch, c)
 
 
+class AxisFold(NamedTuple):
+    """One axis of the slice fold: the HR ``starts`` of the patches along
+    it, each ``patch`` pixels long, over a plane ``extent`` pixels long in
+    which every pixel lies in one or two patches (`_axis_fold`)."""
+    starts: Tuple[int, ...]
+    patch: int
+    extent: int
+
+    def segments(self) -> Tuple[Tuple[int, Tuple[int, ...]], ...]:
+        """The runs of pixels, in order, over which the same patches lie,
+        each ``(length, sources)``: its offsets where the patches lie side
+        by side (patch ``k``'s pixel ``p`` at ``k * patch + p``), one, or
+        two on a seam."""
+        ps = self.patch
+        cuts = sorted(set(self.starts) | {v + ps for v in self.starts})
+        return tuple(
+            (hi - lo, tuple(k * ps + lo - v for k, v in enumerate(self.starts)
+                            if v <= lo and hi <= v + ps))
+            for lo, hi in zip(cuts[:-1], cuts[1:]))
+
+    def weights(self) -> np.ndarray:
+        """1 / the number of patches over each pixel: 1, or 1/2 on a seam."""
+        cnt = np.zeros(self.extent, np.float32)
+        for v in self.starts:
+            cnt[v:v + self.patch] += 1
+        return 1 / cnt
+
+
+def _axis_fold(starts: np.ndarray, patch: int, size: int,
+               scale: int) -> Optional[AxisFold]:
+    """The slice fold of one axis of LR ``starts`` over ``size`` LR pixels,
+    or None where that axis takes the flat scatter: a frame under two
+    patches, or a pixel outside every patch or inside three."""
+    cover = np.zeros(size, np.int64)
+    for start in starts:
+        cover[start:start + patch] += 1
+    if size < 2 * patch or cover.min() < 1 or cover.max() > 2:
+        return None
+    return AxisFold(tuple(int(v) * scale for v in starts), patch * scale,
+                    size * scale)
+
+
+def _grid_folds(pos: np.ndarray, patch: int, scale: int,
+                plane: Tuple[int, int]
+                ) -> Optional[Tuple[AxisFold, AxisFold]]:
+    """Both axes' slice folds of a position list over an LR ``plane``, or
+    None where the grid needs the flat scatter (see `_axis_fold`; a list
+    that is no cartesian grid always does)."""
+    if not _is_cartesian(pos):
+        return None
+    fy = _axis_fold(np.unique(pos[:, 0]), patch, plane[0], scale)
+    fx = _axis_fold(np.unique(pos[:, 1]), patch, plane[1], scale)
+    return None if fy is None or fx is None else (fy, fx)
+
+
 @dataclasses.dataclass(frozen=True, eq=False)     # identity eq: fields hold arrays
 class PatchGeometry:
-    """Device-resident index maps for one (H, W, patch, overlap, scale) tiling.
+    """Device-resident maps for one (H, W, patch, overlap, scale) tiling.
 
     Built once per geometry by :func:`get_geometry` (LRU-cached; also exposed
     as ``ExecutionPlan.geometry``). ``pos`` is in (possibly padded) LR
     coordinates; ``padded_hw >= hw`` only when the frame is smaller than the
     patch, in which case :meth:`fuse_average` crops back to ``hw * scale``.
 
-    Both directions are *separable*: the grid is a cartesian product
-    ``ys x xs``, so extraction takes ``n_y*p`` whole LR rows, then ``n_x*p``
-    whole columns (``y_rows``/``x_cols``), and overlap-add runs as one
-    row-slice scatter along y and one column scatter along x (``n_y*ps +
-    n_x*ps`` fat slices instead of ``N*ps*ps`` scalar rows — the shape XLA
-    tiles well on TPU).
+    The grid is a cartesian product ``ys x xs``, so extraction takes
+    ``n_y*p`` whole LR rows, then ``n_x*p`` whole columns
+    (``y_rows``/``x_cols``). Fusion is the slice fold (``folds``) where
+    the frame is at least two patches a side and every pixel lies in one
+    or two patches along each axis, as in every such grid with ``2 *
+    overlap <= patch`` whose clamped last patch overlaps only its
+    neighbour; any other grid (``folds`` None) takes the flat scatter of
+    `_fusion_maps`.
     """
     hw: Tuple[int, int]            # original LR frame size
     padded_hw: Tuple[int, int]     # reflect-padded (>= patch) LR size
@@ -132,18 +192,16 @@ class PatchGeometry:
     grid_yx: Tuple[int, int]       # (n_y, n_x): pos is their cartesian product
     y_rows: jax.Array              # (n_y*p,) LR row index per patch row
     x_cols: jax.Array              # (n_x*p,) LR col index per patch col
-    y_idx: jax.Array               # (n_y*ps,) HR row index per patch row
-    x_idx: jax.Array               # (n_x*ps,) HR col index per patch col
-    # overlap multiplicity factors per axis (>= 1): the cartesian grid makes
-    # the per-pixel count their outer product, so the cache holds two O(edge)
-    # vectors instead of a full HR-resolution map (~100 KB vs ~133 MB for a
-    # 1080p -> x4 geometry)
-    y_cnt: jax.Array               # (Hp*s,)
-    x_cnt: jax.Array               # (Wp*s,)
+    folds: Optional[Tuple[AxisFold, AxisFold]]   # (y, x); None: the scatter
 
     @property
     def n(self) -> int:
         return len(self.pos)
+
+    @property
+    def fold(self) -> str:
+        """How :meth:`fuse_average` folds: ``"slices"`` or ``"scatter"``."""
+        return "scatter" if self.folds is None else "slices"
 
     @property
     def cache_key(self) -> Tuple[int, int, int, int, int]:
@@ -169,48 +227,81 @@ class PatchGeometry:
         return _extract_grid(img, self.y_rows, self.x_cols, patch=self.patch)
 
     def fuse_average(self, sr_patches: jax.Array) -> jax.Array:
-        """(N, p*s, p*s, C) -> (H*s, W*s, C): separable scatter-add, then a
-        precomputed per-pixel overlap division (overlap-and-average).
+        """(N, p*s, p*s, C) -> (H*s, W*s, C): overlap-and-average, one
+        executable (the slice fold, else the flat scatter and the crop).
 
         Traceable like :meth:`extract`: the fused frame graph calls it on a
-        traced patch tensor, inlining the (already jitted) separable fold."""
-        hp, wp = self.padded_hw
-        s = self.scale
-        n_y, n_x = self.grid_yx
-        out = _fuse_separable(sr_patches, self.y_idx, self.x_idx,
-                              self.y_cnt, self.x_cnt,
-                              n_y=n_y, n_x=n_x, ps=self.patch * s,
-                              hh=hp * s, wh=wp * s)
-        h, w = self.hw
-        if (hp, wp) == (h, w):
-            return out
-        return _fuse_crop(out, hw=(h * s, w * s))
+        traced patch tensor, and the multi-stream graph under ``jax.vmap``."""
+        if self.folds is not None:
+            return _fuse_slices(sr_patches, grid=self.grid_yx,
+                                folds=self.folds)
+        (hp, wp), s = self.padded_hw, self.scale
+        lin, cnt = _fusion_maps(self.pos.tobytes(), self.n, self.patch, wp,
+                                s, hp)
+        return _fuse_scatter(sr_patches, lin, cnt, plane=(hp * s, wp * s),
+                             hw=(self.hw[0] * s, self.hw[1] * s))
 
 
-@phase_jit("essr_fuse", static_argnames=("hw",))
-def _fuse_crop(out, hw):
-    return out[:hw[0], :hw[1]]
+def _fold_rows(u: jax.Array, f: AxisFold) -> jax.Array:
+    """Fold the rows of ``u``, where the patches lie one above the other,
+    into the plane: each segment (`AxisFold.segments`) is one static slice,
+    or on a seam the mean of two, and the segments are laid end to end."""
+    parts = []
+    for length, sources in f.segments():
+        pieces = [u[src:src + length] for src in sources]
+        parts.append(pieces[0] if len(pieces) == 1
+                     else (pieces[0] + pieces[1]) * 0.5)
+    return jnp.concatenate(parts)
 
 
-@phase_jit("essr_fuse", static_argnames=("n_y", "n_x", "ps", "hh", "wh"))
-def _fuse_separable(sr, y_idx, x_idx, y_cnt, x_cnt, *, n_y: int, n_x: int,
-                    ps: int, hh: int, wh: int):
-    """Overlap-and-average over a cartesian patch grid as two axis folds.
+def _fold_columns(u: jax.Array, f: AxisFold) -> jax.Array:
+    """Fold the columns of ``u``, where the patches lie side by side, into
+    the plane by whole patches: the even patches laid at their starts with
+    zeros between, the odd ones the same, the two planes added and
+    weighted (`AxisFold.weights`). Patches of one parity never overlap
+    where no pixel lies in three."""
+    h, ps, c = u.shape[0], f.patch, u.shape[-1]
+    planes = []
+    for parity in (0, 1):
+        parts, end = [], 0
+        for k in range(parity, len(f.starts), 2):
+            if f.starts[k] > end:
+                parts.append(jnp.zeros((h, f.starts[k] - end, c), u.dtype))
+            parts.append(u[:, k * ps:(k + 1) * ps])
+            end = f.starts[k] + ps
+        if end < f.extent:
+            parts.append(jnp.zeros((h, f.extent - end, c), u.dtype))
+        planes.append(jnp.concatenate(parts, axis=1))
+    return (planes[0] + planes[1]) * f.weights()[:, None].astype(u.dtype)
 
-    The per-pixel overlap count is the outer product of the axis counts, so
-    averaging is pre-applied as per-row/per-column reciprocal weights on the
-    patch tensor — no HR-resolution count map is ever materialized outside
-    the jit, and the scatters need no final divide."""
+
+@phase_jit("essr_fuse", static_argnames=("grid", "folds"))
+def _fuse_slices(sr, *, grid: Tuple[int, int],
+                 folds: Tuple[AxisFold, AxisFold]):
+    """Overlap-and-average over a neighbour-only cartesian grid: the
+    patches laid side by side in one transpose, folded along y by segments
+    (`_fold_rows`), then along x by parities (`_fold_columns`). On the TPU
+    x is the lane axis of the plane, where a seam's few columns would fill
+    a few lanes of each vector: whole patches and one weighted add cost
+    less there. A seam's sum has two terms and its weight is a power of
+    two, so the result does not depend on the order of the additions."""
+    n_y, n_x = grid
+    ps, c = sr.shape[1], sr.shape[-1]
+    u = sr.reshape(n_y, n_x, ps, ps, c).transpose(0, 2, 1, 3, 4)
+    u = _fold_rows(u.reshape(n_y * ps, n_x * ps, c), folds[0])
+    return _fold_columns(u, folds[1])
+
+
+@phase_jit("essr_fuse", static_argnames=("plane", "hw"))
+def _fuse_scatter(sr, lin, cnt, *, plane: Tuple[int, int],
+                  hw: Tuple[int, int]):
+    """Overlap-and-average by one flat scatter-add over ``lin`` (see
+    `_fusion_maps`) into an HR ``plane``, cropped to ``hw``."""
     c = sr.shape[-1]
-    wy = jnp.take(1.0 / y_cnt, y_idx).astype(sr.dtype)
-    wx = jnp.take(1.0 / x_cnt, x_idx).astype(sr.dtype)
-    t = sr.reshape(n_y, n_x, ps, ps, c).transpose(0, 2, 1, 3, 4)
-    t = t.reshape(n_y * ps, n_x, ps, c)
-    t = t * wy[:, None, None, None] * wx.reshape(n_x, ps)[None, :, :, None]
-    acc = jnp.zeros((hh, n_x, ps, c), sr.dtype).at[y_idx].add(
-        t, mode="drop")
-    return jnp.zeros((hh, wh, c), sr.dtype).at[:, x_idx].add(
-        acc.reshape(hh, n_x * ps, c), mode="drop")
+    acc = jnp.zeros((plane[0] * plane[1], c), sr.dtype).at[lin].add(
+        sr.reshape(-1, c), mode="drop")
+    out = (acc / cnt.astype(sr.dtype)).reshape(plane[0], plane[1], c)
+    return out[:hw[0], :hw[1]]
 
 
 def _index_maps(pos: np.ndarray, patch: int, plane_w: int, scale: int
@@ -234,7 +325,8 @@ def _axis_idx(starts: np.ndarray, patch: int, scale: int) -> np.ndarray:
 def get_geometry(h: int, w: int, patch: int = 32, overlap: int = 2,
                  scale: int = 4) -> PatchGeometry:
     """The cached geometry for one frame shape — the hot path's only host
-    work, paid once per (H, W, patch, overlap, scale).
+    work, paid once per (H, W, patch, overlap, scale). It decides here,
+    once, which fold serves the geometry's frames (`_grid_folds`).
 
     A `core.caching.BoundedCache` (lru semantics, runtime-resizable):
     `SREngine` sizes it together with the compiled-executable caches via
@@ -242,14 +334,11 @@ def get_geometry(h: int, w: int, patch: int = 32, overlap: int = 2,
     `FrameResult.summary()`."""
     pos, y_rows, x_cols, (hp, wp), (n_y, n_x) = _extract_maps(
         h, w, patch, overlap)
-    ys, xs = np.unique(pos[:, 0]), np.unique(pos[:, 1])
-    y_idx, x_idx, y_cnt, x_cnt = _cartesian_maps(
-        ys.tobytes(), xs.tobytes(), patch, scale, hp, wp)
     return PatchGeometry(
         hw=(h, w), padded_hw=(hp, wp), patch=patch, overlap=overlap,
         scale=scale, pos=pos, grid_yx=(n_y, n_x),
         y_rows=y_rows, x_cols=x_cols,
-        y_idx=y_idx, x_idx=x_idx, y_cnt=y_cnt, x_cnt=x_cnt)
+        folds=_grid_folds(pos, patch, scale, (hp, wp)))
 
 
 @functools.lru_cache(maxsize=128)
@@ -277,42 +366,20 @@ def extract_patches(img: jax.Array, patch: int = 32, overlap: int = 2
     return _extract_grid(img, y_rows, x_cols, patch=patch), pos
 
 
-def _axis_cnt(starts: np.ndarray, patch: int, scale: int,
-              plane: int) -> np.ndarray:
-    """Per-output-pixel coverage multiplicity along one axis (>= 1)."""
-    cnt = np.zeros(plane * scale, np.float32)
-    np.add.at(cnt, _axis_idx(starts, patch, scale), 1.0)
-    return np.maximum(cnt, 1.0)          # pixels no patch covers: avoid 0/0
-
-
-@functools.lru_cache(maxsize=128)
-def _cartesian_maps(ys_bytes: bytes, xs_bytes: bytes, patch: int, scale: int,
-                    plane_h: int, plane_w: int):
-    """Axis index maps + per-axis overlap counts for a cartesian start grid
-    (shared by `get_geometry` and the standalone `fuse_patches_average` fast
-    path), cached per grid. The 2-D count is the outer product of the axis
-    counts, so nothing HR-resolution is ever cached."""
-    ys = np.frombuffer(ys_bytes, dtype=np.int64)
-    xs = np.frombuffer(xs_bytes, dtype=np.int64)
-    return (jnp.asarray(_axis_idx(ys, patch, scale), jnp.int32),
-            jnp.asarray(_axis_idx(xs, patch, scale), jnp.int32),
-            jnp.asarray(_axis_cnt(ys, patch, scale, plane_h)),
-            jnp.asarray(_axis_cnt(xs, patch, scale, plane_w)))
-
-
 @functools.lru_cache(maxsize=4)    # HR-sized entries: keep this tiny
 def _fusion_maps(pos_bytes: bytes, n: int, patch: int, plane_w: int,
                  scale: int, plane_h: int) -> Tuple[jax.Array, jax.Array]:
-    """Scatter map + overlap counts for an arbitrary NON-cartesian position
-    list — the rare standalone-`fuse_patches_average` fallback. Unlike the
-    cartesian maps these are full-plane arrays (the ~133 MB blow-up the
-    separable path avoids), so only a few entries are retained."""
+    """Scatter map + overlap counts for a grid the slice fold cannot take
+    (`_grid_folds`): small frames, three-deep overlaps, arbitrary position
+    lists. These are full-plane arrays, so only a few entries are
+    retained."""
     pos = np.frombuffer(pos_bytes, dtype=np.int64).reshape(n, 2)
     lin = _index_maps(pos, patch, plane_w, scale)
     cnt = np.zeros(plane_h * scale * plane_w * scale, np.float32)
     np.add.at(cnt, lin, 1.0)
     cnt = np.maximum(cnt, 1.0)           # pixels no patch covers: avoid 0/0
-    return jnp.asarray(lin, jnp.int32), jnp.asarray(cnt[:, None])
+    with jax.ensure_compile_time_eval():  # cached: never a tracer
+        return jnp.asarray(lin, jnp.int32), jnp.asarray(cnt[:, None])
 
 
 def _is_cartesian(pos: np.ndarray) -> bool:
@@ -330,9 +397,9 @@ def fuse_patches_average(sr_patches: jax.Array, pos_lr: np.ndarray, scale: int,
     """Overlap-and-average fusion of SR patches (the paper's boundary method).
 
     sr_patches: (N, p*s, p*s, C); pos_lr: LR-space (y,x); out: (H*s, W*s, C).
-    Cartesian-grid positions (the ``grid_starts`` layout) take the separable
-    two-fold scatter; arbitrary position lists fall back to one flat
-    scatter-add over index maps cached per position list.
+    The fold `PatchGeometry.fuse_average` takes: the slice fold where
+    `_grid_folds` allows it, else one flat scatter-add over index maps
+    cached per position list.
     """
     pos = np.asarray(pos_lr, dtype=np.int64)
     ph = int(sr_patches.shape[1])
@@ -341,22 +408,16 @@ def fuse_patches_average(sr_patches: jax.Array, pos_lr: np.ndarray, scale: int,
     # reflect-padded sub-patch-size frames (cropped below).
     plane_h = max(-(-out_hw[0] // scale), int(pos[:, 0].max()) + patch)
     plane_w = max(-(-out_hw[1] // scale), int(pos[:, 1].max()) + patch)
-    c = sr_patches.shape[-1]
-    if _is_cartesian(pos):
-        ys, xs = np.unique(pos[:, 0]), np.unique(pos[:, 1])
-        y_idx, x_idx, y_cnt, x_cnt = _cartesian_maps(
-            ys.tobytes(), xs.tobytes(), patch, scale, plane_h, plane_w)
-        out = _fuse_separable(sr_patches, y_idx, x_idx, y_cnt, x_cnt,
-                              n_y=len(ys), n_x=len(xs), ps=ph,
-                              hh=plane_h * scale, wh=plane_w * scale)
+    folds = _grid_folds(pos, patch, scale, (plane_h, plane_w))
+    if folds is not None:
+        grid = (len(np.unique(pos[:, 0])), len(np.unique(pos[:, 1])))
+        out = _fuse_slices(sr_patches, grid=grid, folds=folds)
         return out[:out_hw[0], :out_hw[1]]
     lin, cnt = _fusion_maps(pos.tobytes(), len(pos), patch, plane_w, scale,
                             plane_h)
-    acc = jnp.zeros((plane_h * scale * plane_w * scale, c), sr_patches.dtype)
-    acc = acc.at[lin].add(sr_patches.reshape(-1, c), mode="drop")
-    out = (acc / cnt.astype(sr_patches.dtype)
-           ).reshape(plane_h * scale, plane_w * scale, c)
-    return out[:out_hw[0], :out_hw[1]]
+    return _fuse_scatter(sr_patches, lin, cnt,
+                         plane=(plane_h * scale, plane_w * scale),
+                         hw=tuple(out_hw))
 
 
 def fuse_patches_crop(sr_patches: jax.Array, pos_lr: np.ndarray, scale: int,

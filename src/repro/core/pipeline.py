@@ -5,8 +5,8 @@ per-subnet batched forward -> thick-overlap overlap+average fusion.
 
 Two execution styles:
   * ``edge_selective_sr``: device-resident serving path. Patch extraction is
-    one cached-index gather, fusion one scatter-add (`PatchGeometry`, cached
-    per frame shape); per-subnet batches are padded to bucketed sizes so jit
+    one cached-index gather, fusion one fold of static slices (`PatchGeometry`,
+    cached per frame shape); per-subnet batches are padded to bucketed sizes so jit
     recompilation is bounded (the shape-static analog of the GLNPU's fixed PE
     array). Routing itself stays host-side: which subnet a patch takes is
     data-dependent, and the host grouping is what keeps each subnet batch
@@ -390,7 +390,7 @@ def _lane_scatter(out, idx, sr):
 # and a trailing ``block_until_ready`` — so frame N+1 cannot start until
 # frame N's full host round-trip completes. The fused path collapses
 # extract -> edge-score -> threshold routing -> capacity-slotted per-subnet
-# forward -> scatter-add fusion into ONE jitted executable per
+# forward -> overlap-average fusion into ONE jitted executable per
 # (geometry, capacity profile): patches are one-hot dispatched into fixed
 # per-subnet capacity slots (the same slot-dispatch shape as
 # distributed/moe.py, and the shape-static analog of the ASIC's fixed PE
@@ -889,7 +889,7 @@ def edge_selective_sr(params: Dict[str, Any], frame: jax.Array, cfg: ESSRConfig,
                 sr = _lane_rows(lane_phase(width))(sr, n=idx.size)
             out_patches = _lane_scatter(out_patches, jnp.asarray(idx), sr)
 
-    with span("essr.fuse"):
+    with span("essr.fuse", fold="loop" if use_loop_reference else g.fold):
         if use_loop_reference:
             img = fuse_patches_average_loop(out_patches, pos, s,
                                             (h * s, w * s))

@@ -125,6 +125,78 @@ def test_vectorized_fuse_matches_loop(h, w, patch, overlap, scale):
         np.asarray(ref), atol=1e-5)
 
 
+def _numpy_overlap_average(sr, g):
+    """Overlap-and-average in numpy, apart from the program: each column of
+    patches slice-added along y into a zero strip, the strips slice-added
+    along x into a zero plane (the program's order, so the comparison can
+    be exact), then divided by the count of patches over each pixel."""
+    n_y, n_x = g.grid_yx
+    s, ps = g.scale, g.patch * g.scale
+    hh, wh = g.hw[0] * s, g.hw[1] * s
+    ys = np.unique(g.pos[:, 0]) * s
+    xs = np.unique(g.pos[:, 1]) * s
+    plane = np.zeros((hh, wh, sr.shape[-1]), np.float32)
+    cnt = np.zeros((hh, wh, 1), np.float32)
+    for j, x in enumerate(xs):
+        strip = np.zeros((hh, ps, sr.shape[-1]), np.float32)
+        for i, y in enumerate(ys):
+            strip[y:y + ps] += sr[i * n_x + j]
+            cnt[y:y + ps, x:x + ps] += 1
+        plane[:, x:x + ps] += strip
+    return plane / cnt
+
+
+@pytest.mark.parametrize("h,w,scale", CELL_GEOMETRIES)
+def test_cell_geometry_fuse_matches_numpy_bit_exact(h, w, scale):
+    """At the cells' frame sizes the slice fold gives, bit for bit, the
+    overlap-average of plain numpy slice-adds."""
+    g = get_geometry(h, w, 32, 2, scale)
+    assert g.fold == "slices"
+    ps = 32 * scale
+    sr = np.random.default_rng(6).uniform(
+        0, 1, (g.n, ps, ps, 3)).astype(np.float32)
+    got = np.asarray(g.fuse_average(jnp.asarray(sr)))
+    np.testing.assert_array_equal(got, _numpy_overlap_average(sr, g))
+
+
+FOLDS = list(zip(SWEEP, ["slices", "scatter", "slices", "scatter",
+                         "scatter", "slices"])) + [
+    ((63, 63, 32, 2, 2), "scatter"),    # pixel 31 lies in three patches
+    ((40, 40, 32, 2, 2), "scatter"),    # under two patches a side
+]
+
+
+@pytest.mark.parametrize("geometry,fold", FOLDS)
+def test_fold_choice_and_result(geometry, fold):
+    """The geometry picks its fold once; either fold matches the seed loop,
+    and the standalone `fuse_patches_average` takes the same fold."""
+    h, w, patch, overlap, scale = geometry
+    g = get_geometry(h, w, patch, overlap, scale)
+    assert g.fold == fold
+    ps = patch * scale
+    sr = jnp.asarray(np.random.default_rng(7).uniform(
+        0, 1, (g.n, ps, ps, 3)).astype(np.float32))
+    got = np.asarray(g.fuse_average(sr))
+    ref = fuse_patches_average_loop(sr, g.pos, scale, (h * scale, w * scale))
+    np.testing.assert_allclose(got, np.asarray(ref), rtol=0, atol=1e-6)
+    np.testing.assert_array_equal(
+        np.asarray(fuse_patches_average(sr, g.pos, scale,
+                                        (h * scale, w * scale))), got)
+
+
+@pytest.mark.parametrize("h,w", [(64, 94), (60, 90)])     # slices; scatter
+def test_geometry_fuse_under_vmap(h, w):
+    """`jax.vmap(geometry.fuse_average)`, as the multi-stream fused graph
+    calls it, fuses each frame of the batch as a lone call does."""
+    g = get_geometry(h, w, 32, 2, 2)
+    sr = jnp.asarray(np.random.default_rng(8).uniform(
+        0, 1, (3, g.n, 64, 64, 3)).astype(np.float32))
+    out = np.asarray(jax.jit(jax.vmap(g.fuse_average))(sr))
+    assert out.shape == (3, 2 * h, 2 * w, 3)
+    for k in range(3):
+        np.testing.assert_array_equal(out[k], np.asarray(g.fuse_average(sr[k])))
+
+
 def test_fuse_average_arbitrary_positions():
     """Non-cartesian position lists take the flat-scatter fallback."""
     pos = np.array([(0, 0), (2, 5)], dtype=np.int64)   # not a product grid

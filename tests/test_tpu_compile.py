@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.patching import get_geometry
 from repro.kernels.dispatch import VMEM_LIMIT_BYTES, tiled_bytes
 from repro.kernels.megakernel import (autotune_report,
                                       essr_forward_megakernel,
@@ -116,3 +117,19 @@ def test_autotuned_block_fits_vmem(quant):
     # the largest block that fits, and one that fills the MXU's rows
     assert used + rep["per_patch_bytes"] > VMEM_LIMIT_BYTES
     assert bp >= rep["mxu_row_floor"]
+
+
+@pytest.mark.parametrize("h,w,scale", [(1080, 1920, 4), (2160, 3840, 2)])
+def test_fuse_compiles_without_scatter_gather_or_kernel(one_chip, h, w,
+                                                        scale):
+    """At the benchmark cells' frame sizes the overlap-average fuse takes
+    the slice fold and compiles for the chip to slices, copies and
+    concatenations: no scatter, no gather, no Pallas call."""
+    g = get_geometry(h, w, PATCH, 2, scale)
+    assert g.fold == "slices"
+    ps = PATCH * scale
+    x = jax.ShapeDtypeStruct((g.n, ps, ps, 3), jnp.float32,
+                             sharding=one_chip)
+    text = jax.jit(g.fuse_average).lower(x).compile().as_text()
+    for op in (" scatter(", " gather(", "tpu_custom_call"):
+        assert op not in text

@@ -70,16 +70,19 @@ def _served(dispatch, trace_dir=None):
     finally:
         if trace_dir is not None:
             jax.profiler.stop_trace()
+    return out, (_host_events(trace_dir) if trace_dir is not None else [])
+
+
+def _host_events(trace_dir):
+    """(name, stats, start_ns) of every host event of the trace, in order."""
+    (path,) = glob.glob(str(trace_dir / "**" / "*.xplane.pb"), recursive=True)
     events = []
-    if trace_dir is not None:
-        (path,) = glob.glob(str(trace_dir / "**" / "*.xplane.pb"),
-                            recursive=True)
-        for plane in ProfileData.from_file(path).planes:
-            if plane.name.startswith("/host:CPU"):
-                for line in plane.lines:
-                    events.extend((e.name, dict(e.stats), e.start_ns)
-                                  for e in line.events)
-    return out, sorted(events, key=lambda e: e[2])
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:CPU"):
+            for line in plane.lines:
+                events.extend((e.name, dict(e.stats), e.start_ns)
+                              for e in line.events)
+    return sorted(events, key=lambda e: e[2])
 
 
 @pytest.fixture(scope="module")
@@ -149,6 +152,27 @@ def test_profiling_changes_no_served_bit(dispatch, request):
     for (img, ids), (img0, ids0) in zip(traced, plain):
         np.testing.assert_array_equal(img, img0)
         np.testing.assert_array_equal(ids, ids0)
+
+
+@pytest.mark.parametrize("hw,fold", [((64, 94), "slices"),
+                                     ((60, 90), "scatter")])
+def test_fuse_span_names_its_fold(hw, fold, tmp_path):
+    """``essr.fuse`` carries the stat ``fold``: ``"slices"`` where every
+    pixel lies in at most two neighbouring patches along each axis,
+    ``"scatter"`` on a frame under two patches a side."""
+    eng = _engine("host")
+    frame = jnp.asarray(np.random.default_rng(1).uniform(
+        0, 1, (*hw, 3)).astype(np.float32))
+    eng.upscale(frame).image.block_until_ready()      # warm
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.upscale(frame).image.block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    folds = [stats.get("fold") for name, stats, _ in _host_events(tmp_path)
+             if name == "essr.fuse"]
+    assert folds == [fold]
+    assert get_geometry(*hw, 32, 2, CFG.scale).fold == fold
 
 
 @pytest.mark.parametrize("hw", [(60, 90), (20, 50)])    # no pad; pad
